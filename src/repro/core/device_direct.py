@@ -21,9 +21,9 @@ Two placement shapes:
   * `read_tensors`: BATCHED placement for LLM ingest (weight shards,
     token batches). Tensors are packed back-to-back into ring slots; each
     slot costs one vectored splice batch (`pread_into_many` — a single
-    DPU doorbell in dpu mode) and ONE `jax.device_put` for the whole
+    DPU doorbell in dpu mode) and one `jax.device_put` per dtype in the
     packed slot instead of one per tensor, with per-tensor arrays carved
-    on-device (bitcast + reshape — no host copies). The ring is
+    on-device (slice + reshape — no host copies). The ring is
     double-buffered: while slot k's host->device DMA is in flight, slot
     k+1's splice proceeds, so placement and device transfer overlap
     across the batch.
@@ -51,25 +51,44 @@ import numpy as np
 
 
 @partial(jax.jit, static_argnums=(1,))
-def _carve_packed(packed: jax.Array, layout: Tuple) -> Tuple[jax.Array, ...]:
-    """Carve every tensor of a packed slot out of its on-device uint8
-    buffer in ONE dispatched (and layout-cached) computation: slice +
-    bitcast + reshape per tensor, fused by XLA — no host copies and no
-    per-tensor dispatch. `layout` is a static tuple of (start_byte, shape,
-    dtype_name); steady-state ingest reuses layouts, so this compiles
-    once per pack shape."""
+def _carve_packed(groups: Tuple[jax.Array, ...],
+                  layout: Tuple) -> Tuple[jax.Array, ...]:
+    """Carve every tensor of a packed slot out of its on-device buffers in
+    ONE dispatched (and layout-cached) computation. Each slot arrives as
+    one flat array per dtype (`groups`), so a tensor is a slice + reshape
+    of its group: no bitcast, and so no narrow (n, itemsize) intermediate,
+    which the TPU would lay out on (8, 128) tiles at up to 128x the slot's
+    bytes. `layout` is a static tuple of (group, start_elem, shape);
+    steady-state ingest reuses layouts, so this compiles once per pack
+    shape."""
     out = []
-    for start, shape, dtype_name in layout:
-        np_dtype = np.dtype(dtype_name)
-        nbytes = int(np.prod(shape)) * np_dtype.itemsize
-        seg = packed[start:start + nbytes]
-        if np_dtype.itemsize > 1:
-            seg = jax.lax.bitcast_convert_type(
-                seg.reshape(-1, np_dtype.itemsize), np_dtype)
-        else:
-            seg = jax.lax.bitcast_convert_type(seg, np_dtype)
-        out.append(seg.reshape(shape))
+    for g, start, shape in layout:
+        n = int(np.prod(shape))
+        out.append(groups[g][start:start + n].reshape(shape))
     return tuple(out)
+
+
+def _pack_slot(pack) -> Tuple[List[Tuple[np.dtype, int, int]], list]:
+    """Lay one slot's tensors out grouped by dtype, each group contiguous
+    and every tensor aligned to its itemsize, so each group's byte range
+    is a plain `view(dtype)` of the ring. `pack` is [(ix, fd, off, shape,
+    dtype, size)]. Returns ([(dtype, start_byte, end_byte)] per group,
+    [(ix, fd, off, size, pos, group, start_elem, shape)] per tensor)."""
+    order: List[np.dtype] = []
+    for *_x, np_dtype, _size in pack:
+        if np_dtype not in order:
+            order.append(np_dtype)
+    groups, placed, used = [], [], 0
+    for g, np_dtype in enumerate(order):
+        used = -(-used // np_dtype.itemsize) * np_dtype.itemsize
+        g0 = used
+        for ix, fd, off, shape, dt, size in pack:
+            if dt == np_dtype:
+                placed.append((ix, fd, off, size, used, g,
+                               (used - g0) // np_dtype.itemsize, shape))
+                used += size
+        groups.append((np_dtype, g0, used))
+    return groups, placed
 
 
 @dataclass
@@ -166,8 +185,8 @@ class DeviceDirectSink:
         """Batched device-direct placement: `reqs` is [(fd, offset, shape,
         dtype), ...]. Tensors are packed back-to-back into ring slots; per
         slot this costs ONE vectored splice batch (`pread_into_many` — a
-        single DPU doorbell in dpu mode) and ONE `jax.device_put`, with
-        per-tensor arrays carved on-device. Double-buffered: slot k+1's
+        single DPU doorbell in dpu mode) and one `jax.device_put` per dtype
+        present in the slot, with per-tensor arrays carved on-device. Double-buffered: slot k+1's
         splice overlaps slot k's host->device DMA; a slot is only reused
         after its carved tensors materialized (so the DMA source is never
         overwritten in flight). With `sharding`, carved tensors are
@@ -184,41 +203,48 @@ class DeviceDirectSink:
         i = 0
         while i < len(parsed):
             # greedy pack: as many consecutive tensors as fit in one slot
-            pack, used = [], 0
+            # (bytes plus each dtype group's worst-case alignment pad)
+            pack, need, dtypes = [], 0, set()
             while i < len(parsed):
                 fd, off, shape, np_dtype = parsed[i]
                 size = int(np.prod(shape)) * np_dtype.itemsize
-                if used + size > self.slot_bytes:
+                pad = 0 if np_dtype in dtypes else np_dtype.itemsize - 1
+                if pack and need + size + pad > self.slot_bytes:
                     break
-                pack.append((i, fd, off, shape, np_dtype, used, size))
-                used += size
+                pack.append((i, fd, off, shape, np_dtype, size))
+                need += size + pad
+                dtypes.add(np_dtype)
                 i += 1
+            groups, placed = _pack_slot(pack)
             slot = self._acquire()          # blocks iff the slot's previous
             try:                            # tensors are still in flight
                 base = slot * self.slot_bytes
                 self.client.pread_into_many(
                     [(fd, size, off, base + pos)
-                     for _ix, fd, off, _sh, _dt, pos, size in pack],
+                     for _ix, fd, off, size, pos, *_rest in placed],
                     self.ring)
-                packed = jax.device_put(self.ring.buf[base:base + used])
-                layout = tuple((pos, shape, np_dtype.name)
-                               for _ix, _fd, _off, shape, np_dtype, pos,
-                               _size in pack)
+                # one host->device DMA per dtype group, typed on the host
+                packed = tuple(
+                    jax.device_put(self.ring.buf[base + g0:base + g1]
+                                   .view(np_dtype))
+                    for np_dtype, g0, g1 in groups)
+                layout = tuple((g, start, shape)
+                               for *_x, g, start, shape in placed)
                 carved = _carve_packed(packed, layout)
-                for (ix, *_rest), arr in zip(pack, carved):
+                for (ix, *_rest), arr in zip(placed, carved):
                     if sharding is not None:
                         arr = jax.device_put(arr, sharding)
                         self.stats.device_puts += 1
                     out[ix] = arr
-                self.stats.device_puts += 1
+                self.stats.device_puts += len(groups)
                 self.stats.batches += 1
-                self.stats.reads += len(pack)
-                self.stats.bytes += used
+                self.stats.reads += len(placed)
+                self.stats.bytes += sum(p[3] for p in placed)
                 # hand the slot back immediately; the NEXT user of this
                 # slot blocks on these arrays (in _acquire) before
                 # refilling it, so up to n_slots pipelines overlap
                 with self._cv:
-                    self._inflight[slot] = [out[p[0]] for p in pack]
+                    self._inflight[slot] = [out[p[0]] for p in placed]
             finally:
                 self._release(slot)
         # the returned batch is fully materialized (callers may mutate or

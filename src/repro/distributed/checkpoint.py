@@ -25,12 +25,8 @@ import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+import ml_dtypes  # noqa: F401  (registers 'bfloat16' etc. with numpy)
 import numpy as np
-
-try:                       # registers 'bfloat16' etc. with numpy
-    import ml_dtypes       # noqa: F401
-except ImportError:        # pragma: no cover
-    pass
 
 _STEP_RE = re.compile(r"^step-(\d+)$")
 

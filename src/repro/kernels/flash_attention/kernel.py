@@ -141,15 +141,11 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         pltpu.VMEM((block_q, LANES), jnp.float32),
         pltpu.VMEM((block_q, D), jnp.float32),
     ]
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except TypeError:                                    # older field name
-        params = None
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
     call = pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret,
-        **({"compiler_params": params} if params is not None else {}))
+        interpret=interpret, compiler_params=params)
     return tuple(call(q, k, v))

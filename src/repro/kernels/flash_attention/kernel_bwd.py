@@ -142,13 +142,9 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, scale: float,
 
     common = dict(scale=scale, causal=causal, window=window, seq_k=seq_k,
                   block_q=block_q, block_k=block_k)
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except TypeError:
-        params = None
-    pk = {"compiler_params": params} if params is not None else {}
+    pk = {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))}
 
     q_spec = pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0))
     q_spec_T = pl.BlockSpec((1, block_q, 1, D),

@@ -14,15 +14,22 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _tile_block(n: int, block: int) -> int:
+    """Words per grid step: `block` capped at the padded input, rounded
+    up to whole (8, 128) u32 tiles."""
+    t = K.TILE_WORDS
+    return -(-min(block, max(n, 1)) // t) * t
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _checksum_words(words: jax.Array, block: int, interpret: bool):
     n = words.shape[0]
-    blk = min(block, max(n, 8))
+    blk = _tile_block(n, block)
     pad = (-n) % blk
     w = jnp.pad(words.astype(jnp.uint32), (0, pad))
-    out = K.fletcher_tiles(w.reshape(-1, blk), n_total=n, block=blk,
-                           interpret=interpret)
-    return out[0]
+    parts = K.fletcher_tiles(w.reshape(-1, K.LANES), n_total=n, block=blk,
+                             interpret=interpret)
+    return jnp.sum(parts.reshape(2, -1), axis=1, dtype=jnp.uint32)
 
 
 def fletcher_checksum(x: jax.Array, *, block: int = K.DEFAULT_BLOCK,

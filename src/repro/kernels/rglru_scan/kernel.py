@@ -65,15 +65,11 @@ def rglru_scan_tiles(a: jax.Array, b: jax.Array, h0: jax.Array, *,
         pl.BlockSpec((1, block_r), lambda b_, r, t: (b_, r)),
     ]
     out_spec = pl.BlockSpec((1, block_t, block_r), lambda b_, r, t: (b_, t, r))
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:
-        params = None
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     call = pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, R), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
-        interpret=interpret,
-        **({"compiler_params": params} if params is not None else {}))
+        interpret=interpret, compiler_params=params)
     return call(a, b, h0)

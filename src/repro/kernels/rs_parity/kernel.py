@@ -58,11 +58,6 @@ def rs_matmul_tiles(mat: jax.Array, x: jax.Array, *,
     nb, s, tile = x.shape
     m = mat.shape[0]
     kern = functools.partial(_rs_matmul_kernel, m=m, s=s)
-    try:
-        mk = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-        params = mk(dimension_semantics=("arbitrary",))
-    except (AttributeError, TypeError):
-        params = None
     call = pl.pallas_call(
         kern, grid=(nb,),
         in_specs=[pl.BlockSpec((m, s), lambda i: (0, 0)),
@@ -70,5 +65,6 @@ def rs_matmul_tiles(mat: jax.Array, x: jax.Array, *,
         out_specs=pl.BlockSpec((1, m, tile), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, m, tile), jnp.int32),
         interpret=interpret,
-        **({"compiler_params": params} if params is not None else {}))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)))
     return call(mat, x)

@@ -49,15 +49,19 @@ def gf_matmul(mat, cells, *, tile: int = K.DEFAULT_TILE,
                          "cell rows")
     if m == 0 or cells.shape[1] == 0:
         return jnp.zeros((m, cells.shape[1]), jnp.uint8)
+    eff = _effective_tile(cells.shape[1], tile, bool(interpret))
+    return _gf_matmul(mat, cells, m, s, eff, bool(interpret))
+
+
+def _effective_tile(n: int, tile: int, interpret: bool) -> int:
+    """Bytes of each cell per grid step for an n-byte cell row."""
     if interpret:
         # Interpret-mode grid steps carry heavy per-step overhead; one
         # lane-padded tile per cell keeps the XLA lowering to a single
         # fused elementwise chain (~100s of MB/s on CPU vs ~3 with 1 KiB
         # tiles). Real TPU lowering keeps the bounded VMEM tile instead.
-        eff = min(2 << 20, -(-cells.shape[1] // 128) * 128)
-    else:
-        eff = min(tile, max(128, cells.shape[1]))
-    return _gf_matmul(mat, cells, m, s, eff, bool(interpret))
+        return min(2 << 20, -(-n // 128) * 128)
+    return min(tile, max(128, n))
 
 
 def ec_encode(cells, p: int, *, tile: int = K.DEFAULT_TILE,

@@ -105,16 +105,12 @@ def wkv_chunked_tiles(r, k, v, w, u, s0, *, chunk: int = DEFAULT_CHUNK,
         jax.ShapeDtypeStruct((B, T, H, hd), jnp.float32),
         jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
     ]
-    try:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:
-        params = None
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     call = pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
-        **({"compiler_params": params} if params is not None else {}))
+        interpret=interpret, compiler_params=params)
     y, s = call(r, k, v, w, u, s0)
     return y, s

@@ -14,9 +14,9 @@ inline by the DPU decrypt on-device:
     x  ^= x >> 16
     out = data ^ x
 
-Fully parallel over u32 words: the grid streams (1, block) tiles through
-VMEM with pure VPU work, so throughput is HBM-bound — the right shape for
-an inline service.
+Fully parallel over u32 words: the grid streams (rows, 128) tiles
+through VMEM with pure VPU work, so throughput is HBM-bound — the right
+shape for an inline service.
 """
 from __future__ import annotations
 
@@ -27,7 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 2048
+LANES = 128
+TILE_WORDS = 8 * LANES          # one (8, 128) u32 tile
+DEFAULT_BLOCK = 2048            # u32 words per grid step
 GOLDEN32 = 0x9E3779B9
 
 
@@ -43,29 +45,31 @@ def keystream_u32(idx: jax.Array, key: int, nonce: int) -> jax.Array:
     return x
 
 
-def _cipher_kernel(x_ref, out_ref, *, key: int, nonce: int, block: int):
+def _cipher_kernel(x_ref, out_ref, *, key: int, nonce: int, rows: int):
     i = pl.program_id(0)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1).astype(jnp.uint32)
-    idx = idx + (i * block).astype(jnp.uint32)
-    ks = keystream_u32(idx, key, nonce)
-    out_ref[...] = x_ref[...] ^ ks
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    idx = ((i * rows + r) * LANES + lane).astype(jnp.uint32)
+    out_ref[...] = x_ref[...] ^ keystream_u32(idx, key, nonce)
 
 
 def cipher_tiles(words: jax.Array, key: int, nonce: int, *,
+                 block: int = DEFAULT_BLOCK,
                  interpret: bool = False) -> jax.Array:
-    """words: u32 (n_blocks, block). Returns XOR-ciphered words (same shape).
+    """words: u32 (n_rows, 128), n_rows a multiple of block // 128 (block
+    a multiple of TILE_WORDS). Returns XOR-ciphered words (same shape).
     Involution: applying twice restores the input."""
-    nb, blk = words.shape
-    kern = functools.partial(_cipher_kernel, key=key, nonce=nonce, block=blk)
-    try:
-        params = pltpu.CompilerParams(dimension_semantics=("parallel",))
-    except TypeError:
-        params = None
+    n_rows, lanes = words.shape
+    rows = block // LANES
+    if lanes != LANES or block % TILE_WORDS or n_rows % rows:
+        raise ValueError(f"words {words.shape} do not tile by {block}")
+    kern = functools.partial(_cipher_kernel, key=key, nonce=nonce, rows=rows)
+    spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     call = pl.pallas_call(
-        kern, grid=(nb,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, blk), jnp.uint32),
+        kern, grid=(n_rows // rows,),
+        in_specs=[spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((n_rows, LANES), jnp.uint32),
         interpret=interpret,
-        **({"compiler_params": params} if params is not None else {}))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)))
     return call(words)

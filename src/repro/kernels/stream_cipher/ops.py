@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.fletcher.ops import _tile_block
 from repro.kernels.stream_cipher import kernel as K
 
 
@@ -18,10 +19,10 @@ def _interpret_default() -> bool:
                    static_argnames=("key", "nonce", "block", "interpret"))
 def _cipher_words(words, key, nonce, block, interpret):
     n = words.shape[0]
-    blk = min(block, max(n, 8))
+    blk = _tile_block(n, block)
     pad = (-n) % blk
     w = jnp.pad(words.astype(jnp.uint32), (0, pad))
-    out = K.cipher_tiles(w.reshape(-1, blk), key, nonce,
+    out = K.cipher_tiles(w.reshape(-1, K.LANES), key, nonce, block=blk,
                          interpret=interpret)
     return out.reshape(-1)[:n]
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import TrainConfig
 from repro.configs import get_config
 from repro.core.client import ROS2Client
@@ -77,6 +78,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg, api, mctx, client = build(args)
     need = args.tokens or (args.steps * args.global_batch
                            * (args.seq + 1) + args.seq + 1)

@@ -11,19 +11,10 @@ from repro.models.params import DEFAULT_RULES
 
 
 def make_mesh(shape, axis_names, devices=None):
-    """jax.make_mesh across JAX versions: newer releases take (and some
-    require) axis_types=jax.sharding.AxisType.*; older ones don't have the
-    enum at all. Try the typed form first, fall back to the plain call."""
+    """jax.make_mesh with every axis in Auto sharding mode."""
     kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axis_names,
-                                 axis_types=(axis_type.Auto,) * len(shape),
-                                 **kw)
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axis_names, **kw)
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
+    return jax.make_mesh(shape, axis_names, axis_types=auto, **kw)
 
 
 @dataclass

@@ -29,7 +29,7 @@ from tools.analysis import leakwitness, lockgraph  # noqa: E402
 # Modules that exercise the storage stack end to end (construct clients
 # or sinks); the leak witness applies to each of them.
 STORAGE_MODULES = {
-    "test_checkpoint", "test_cluster", "test_control_plane",
+    "test_checkpoint", "test_chip_smoke", "test_cluster", "test_control_plane",
     "test_core_storage", "test_device_direct", "test_direct_read_path",
     "test_erasure", "test_fault_storage", "test_pipeline",
     "test_properties", "test_serve", "test_sg_data_path",

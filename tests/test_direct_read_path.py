@@ -3,8 +3,9 @@ bit-identical to the staged path (including extents straddling destination
 spans), never touch the staging ring, and respect destination
 capabilities; quorum writes return at majority with stragglers landing in
 the background, post-ack failures demoting + re-replicating; the batched
-DeviceDirectSink packs tensors into slots (one device_put per slot, no
-session leak); the MediaScrubber ties its budget to device idle time."""
+DeviceDirectSink packs tensors into slots (one device_put per dtype
+group of a slot, no session leak); the MediaScrubber ties its budget to
+device idle time."""
 import threading
 import time
 
@@ -362,8 +363,39 @@ def test_read_tensors_batched_matches_and_packs(mode):
             np.testing.assert_array_equal(np.asarray(g), t)
         # the batching claim: strictly fewer device transfers than tensors
         assert sink.stats.device_puts < len(tensors)
-        assert sink.stats.device_puts == sink.stats.batches
+        # one slot, one host->device transfer per dtype group in it
+        assert sink.stats.batches == 1
+        assert sink.stats.device_puts == len({t.dtype for t in tensors})
         assert sink.stats.reads == len(tensors)
+    c.close()
+
+
+def test_read_tensors_mixed_widths_group_by_dtype():
+    """Odd-length narrow tensors between wide ones: each dtype group is
+    laid out aligned to its itemsize and carved without a bitcast."""
+    import ml_dtypes
+    from repro.core.device_direct import DeviceDirectSink
+    c = ROS2Client(mode="host", transport="rdma")
+    rng = np.random.default_rng(11)
+    tensors = [rng.integers(0, 255, (37,)).astype(np.uint8),
+               rng.standard_normal((3, 5)).astype(ml_dtypes.bfloat16),
+               rng.standard_normal((7, 3)).astype(np.float32),
+               rng.integers(0, 255, (5, 3)).astype(np.uint8),
+               rng.standard_normal((9,)).astype(ml_dtypes.bfloat16),
+               rng.integers(-9, 9, (11,), dtype=np.int32)]
+    reqs = []
+    for i, t in enumerate(tensors):
+        fd = c.open(f"/mixed{i}", create=True)
+        c.pwrite(fd, t.tobytes(), 0)
+        reqs.append((fd, 0, t.shape, t.dtype))
+    with DeviceDirectSink(c, slot_bytes=4096, n_slots=2) as sink:
+        got = sink.read_tensors(reqs)
+        for g, t in zip(got, tensors):
+            assert g.dtype == t.dtype and g.shape == t.shape
+            np.testing.assert_array_equal(
+                np.asarray(g).view(np.uint8), t.view(np.uint8))
+        assert sink.stats.batches == 1
+        assert sink.stats.device_puts == 4    # u8, bf16, f32, i32 groups
     c.close()
 
 

@@ -13,8 +13,11 @@ from repro.data.pipeline import (Assignment, ROS2TokenLoader, coverage_check,
                                  read_meta, write_token_shards)
 
 
-@pytest.fixture(scope="module")
+@pytest.fixture
 def corpus_client():
+    # per test, so the leak witness tracks the client and closes it: the
+    # loader's reads lazily start the client's dispatch pool, whose
+    # threads live until the client closes
     client = ROS2Client(mode="host", transport="rdma")
     tokens = np.arange(40_000, dtype=np.int32) % 997
     write_token_shards(client, "/data", tokens, shard_tokens=4096)
