@@ -67,6 +67,7 @@ same calibrated model the paper-figure benchmarks use.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -79,6 +80,7 @@ import numpy as np
 
 from repro.core import transport_model as tm
 from repro.core import counters_registry
+from repro.core import tracing
 from repro.core.control_plane import ControlPlane
 from repro.core.data_plane import (AccessError, MemoryRegion,
                                    MemoryRegistry, RDMATransport,
@@ -636,6 +638,22 @@ class _SubmitReap:
             pool.shutdown(wait=True)
 
 
+def _target_verb(verb: str, nbytes: Callable[..., int]):
+    """Span a `_ServerIO` data or cell verb as `ros2.target`. `nbytes`
+    gives the verb's payload bytes from its arguments; it runs only while
+    spans are on."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def verb_call(self, *args, **kwargs):
+            if not tracing.active():
+                return fn(self, *args, **kwargs)
+            with tracing.span("ros2.target", tid=self.label, verb=verb,
+                              bytes=nbytes(*args, **kwargs)):
+                return fn(self, *args, **kwargs)
+        return verb_call
+    return wrap
+
+
 class _ServerIO(_SubmitReap):
     """ONE engine target's data-plane session (and, for a single-target
     deployment, the whole transport-aware I/O adapter DFSClient uses).
@@ -894,6 +912,8 @@ class _ServerIO(_SubmitReap):
         data-plane mechanics)."""
         return self.submit_writev(oid, offset, buffers, _inline=True).wait()
 
+    @_target_verb("writev", lambda oid, offset, buffers:
+                  sum(memoryview(b).nbytes for b in buffers))
     def _writev_impl(self, oid: int, offset: int, buffers: Sequence) -> int:
         """Scatter-gather write: every iovec buffer is registered once
         (zero-copy wrap, no concatenation), moved in ring-sized SG batches
@@ -1192,6 +1212,8 @@ class _ServerIO(_SubmitReap):
                 self._active_reads -= 1
         return size
 
+    @_target_verb("read_into", lambda oid, offset, dsts:
+                  sum(sz for _mr, _off, sz in dsts))
     def _gather_into(self, oid: int, offset: int,
                      dsts: Sequence) -> int:
         """Shared gather core: direct splice when the transport supports
@@ -1339,6 +1361,8 @@ class _ServerIO(_SubmitReap):
     # this plane. Parity cells live at block-relative offsets >= BLOCK —
     # virtual addresses the file-offset API can never reach.
 
+    @_target_verb("update_cell", lambda oid, block, cell_off, payload:
+                  memoryview(payload).nbytes)
     def update_cell(self, oid: int, block: int, cell_off: int,
                     payload) -> None:
         """Write one EC cell: same admission, staging-ring, transport-SG
@@ -1381,6 +1405,8 @@ class _ServerIO(_SubmitReap):
         finally:
             self.creg.deregister(mr)
 
+    @_target_verb("xor_apply", lambda oid, block, cell_off, delta:
+                  memoryview(delta).nbytes)
     def xor_apply(self, oid: int, block: int, cell_off: int,
                   delta) -> None:
         """Ship one parity DELTA and apply it target-side — the delta-
@@ -1423,6 +1449,7 @@ class _ServerIO(_SubmitReap):
         finally:
             self.creg.deregister(mr)
 
+    @_target_verb("fetch_cell", lambda oid, block, cell_off, ln: ln)
     def fetch_cell(self, oid: int, block: int, cell_off: int,
                    ln: int) -> np.ndarray:
         """Read one EC cell's raw media bytes through the staged transport
@@ -1886,11 +1913,14 @@ class _ClusterRouter(_SubmitReap):
         # one target may execute at once, whether they come from the async
         # submit surface or the striping pool's concurrent per-target tasks
         ring = self._target_ring(tid)
-        ring.acquire()
+        sess = self.sessions[tid]
+        with tracing.span("ros2.router.sq_wait", tid=sess.label):
+            ring.acquire()
         try:
-            sess = self.sessions[tid]
-            for fo, payload in runs:
-                call(sess, oid, fo, payload)
+            with tracing.span("ros2.router.batch", tid=sess.label,
+                              runs=len(runs)):
+                for fo, payload in runs:
+                    call(sess, oid, fo, payload)
         finally:
             ring.release()
 
@@ -2078,10 +2108,11 @@ class _ClusterRouter(_SubmitReap):
     # the rest finishing in background, and reads reconstruct missing
     # cells from any k clean survivors.
 
-    def _ec_order(self, oid: int, b: int) -> List[int]:
+    def _ec_order(self, oid: int, b: int, op: int = 0) -> List[int]:
         with self._map_lock:
             k, p, _cs = self._ec
-        order = list(self._placement(oid, str(b)))
+        with tracing.span("ros2.ec.place", op=op):
+            order = list(self._placement(oid, str(b)))
         if len(order) < k + p:
             raise StorageError(
                 f"ec({k},{p}) needs {k + p} targets, pool map has "
@@ -2105,28 +2136,31 @@ class _ClusterRouter(_SubmitReap):
         with self._map_lock:
             self._ec_pending = [f for f in self._ec_pending if not f.done()]
 
-    def _ec_drain(self) -> None:
+    def _ec_drain(self, op: int = 0) -> None:
         """Join every in-flight straggler cell write (counters snapshots
         and close() want a quiesced stripe state)."""
         with self._map_lock:
             pend, self._ec_pending = self._ec_pending, []
-        for f in pend:
-            f.result()
+        with tracing.span("ros2.ec.drain", op=op, pending=len(pend)):
+            for f in pend:
+                f.result()
 
     def _ec_mark_dirty(self, oid: int, b: int,
-                       cells: Sequence[int]) -> None:
+                       cells: Sequence[int], op: int = 0) -> None:
         """Record dropped cells in the dirty ledger of every UP target (a
         union survives any single ledger holder dying); at least one copy
         must land or the write cannot safely ack."""
         with self._map_lock:
             tids = [t for t in self._tids if self._up.get(t)]
         landed = 0
-        for tid in tids:
-            try:
-                self.sessions[tid].mark_cells(oid, b, cells)
-                landed += 1
-            except StorageError:
-                continue
+        with tracing.span("ros2.ec.ledger", op=op, verb="mark",
+                          targets=len(tids)):
+            for tid in tids:
+                try:
+                    self.sessions[tid].mark_cells(oid, b, cells)
+                    landed += 1
+                except StorageError:
+                    continue
         if not landed:
             raise StorageError(
                 f"ec dirty marker for cells {list(cells)} of block {b} "
@@ -2148,7 +2182,7 @@ class _ClusterRouter(_SubmitReap):
             note_recovery(self._faults, "ec.cell_retry")
             return out
 
-    def _ec_read_dirty(self, oid: int, b: int) -> set:
+    def _ec_read_dirty(self, oid: int, b: int, op: int = 0) -> set:
         """The fleet-union dirty-cell set for one stripe (unreachable
         ledger holders tolerated — their stale copy only re-triggers an
         idempotent rebuild later)."""
@@ -2156,26 +2190,30 @@ class _ClusterRouter(_SubmitReap):
         with self._map_lock:
             tids = [t for t in self._tids if self._up.get(t)]
         out: set = set()
-        for tid in tids:
-            try:
-                marks = self.sessions[tid].read_markers(oid, b, k + p)
-            except StorageError:
-                continue
-            out |= {i for i, byte in enumerate(marks) if byte}
+        with tracing.span("ros2.ec.ledger", op=op, verb="read",
+                          targets=len(tids)):
+            for tid in tids:
+                try:
+                    marks = self.sessions[tid].read_markers(oid, b, k + p)
+                except StorageError:
+                    continue
+                out |= {i for i, byte in enumerate(marks) if byte}
         return out
 
     def _ec_clear_dirty(self, oid: int, b: int,
-                        cells: Sequence[int]) -> None:
+                        cells: Sequence[int], op: int = 0) -> None:
         if not cells:
             return
         k, p, _cs = self._ec
         with self._map_lock:
             tids = [t for t in self._tids if self._up.get(t)]
-        for tid in tids:
-            try:
-                self.sessions[tid].clear_cells(oid, b, cells, k + p)
-            except StorageError:
-                continue
+        with tracing.span("ros2.ec.ledger", op=op, verb="clear",
+                          targets=len(tids)):
+            for tid in tids:
+                try:
+                    self.sessions[tid].clear_cells(oid, b, cells, k + p)
+                except StorageError:
+                    continue
 
     def _ec_writev(self, oid: int, offset: int, buffers: Sequence) -> int:
         from repro.kernels.rs_parity import ops as rs
@@ -2189,12 +2227,15 @@ class _ClusterRouter(_SubmitReap):
         data = arrs[0] if len(arrs) == 1 else np.concatenate(arrs)
         pos = 0
         for b, bo, ln in split_blocks(offset, total):
-            self._ec_write_block(rs, oid, b, bo, data[pos:pos + ln])
+            op = tracing.next_op_id()
+            with tracing.span("ros2.ec.write", op=op, block=b) as sp:
+                sp.set_metadata(path=self._ec_write_block(
+                    rs, oid, b, bo, data[pos:pos + ln], op=op))
             pos += ln
         return total
 
     def _ec_write_block(self, rs, oid: int, b: int, bo: int,
-                        frag: np.ndarray) -> None:
+                        frag: np.ndarray, op: int = 0) -> str:
         """One stripe's write: parity over the zero-padded full block in
         the media domain (partial writes read-modify-write the stripe
         image first), then a parallel fan-out of the touched data cells
@@ -2220,11 +2261,14 @@ class _ClusterRouter(_SubmitReap):
         instead — it never reads the untouched k-|touched| cells. This
         full path survives as the stripe-covering write, the heal-on-write
         path, and the counted fallback when the delta path's
-        prerequisites fail (`ec.delta_fallbacks`)."""
+        prerequisites fail (`ec.delta_fallbacks`).
+
+        Returns the path taken: "delta" or "full"."""
         k, p, cs = self._ec
         ln = int(frag.size)
-        order = self._ec_order(oid, b)
-        pre_dirty = {c for c in self._ec_read_dirty(oid, b) if c < k + p}
+        order = self._ec_order(oid, b, op=op)
+        pre_dirty = {c for c in self._ec_read_dirty(oid, b, op=op)
+                     if c < k + p}
         partial = not (bo == 0 and ln == BLOCK)
         dtouch = sorted(set(range(bo // cs, (bo + ln - 1) // cs + 1)))
         if partial and not pre_dirty and len(dtouch) < k:
@@ -2234,8 +2278,8 @@ class _ClusterRouter(_SubmitReap):
                    for c in dtouch + list(range(k, k + p))):
                 try:
                     self._ec_write_block_delta(rs, oid, b, bo, frag,
-                                               order, dtouch)
-                    return
+                                               order, dtouch, op=op)
+                    return "delta"
                 except _EcDeltaUnavailable:
                     pass          # prerequisites lost mid-op: re-encode
             with self._map_lock:
@@ -2247,7 +2291,11 @@ class _ClusterRouter(_SubmitReap):
         else:
             media = self._ec_read_media_block(rs, oid, b)
             media[bo:bo + ln] = self._ec_media_image(frag, oid, b, bo)
-        parity = np.asarray(rs.ec_encode(media.reshape(k, cs), p))
+        with tracing.span("ros2.ec.parity.submit", op=op,
+                          bytes=media.size):
+            pending = rs.ec_encode(media.reshape(k, cs), p)
+        with tracing.span("ros2.ec.parity.wait", op=op):
+            parity = np.asarray(pending)
         jobs: List[Tuple[int, Callable[[_ServerIO], None]]] = []
         touched = set(range(bo // cs, (bo + ln - 1) // cs + 1))
         for i in sorted(touched):
@@ -2275,60 +2323,66 @@ class _ClusterRouter(_SubmitReap):
                 f"{len(set(down) | stale_down)} cells dirty "
                 "— stripe would fall below k clean cells")
         if down:
-            self._ec_mark_dirty(oid, b, down)
+            self._ec_mark_dirty(oid, b, down, op=op)
 
         failed: List[int] = []
         flock = threading.Lock()
 
         def run(cell: int, fn) -> None:
-            try:
-                self._ec_retry(lambda: fn(self.sessions[order[cell]]))
-            except StorageError:
-                # cell-level failure — target down OR the single-copy
-                # media commit failed: either way the cell is suspect,
-                # so ledger it (idempotent) and let rebuild regenerate
-                # it from survivors; parity absorbs media loss exactly
-                # like target loss
-                with flock:
-                    failed.append(cell)
-                self._ec_mark_dirty(oid, b, [cell])
-                note_recovery(self._faults, "ec.cell_write_degraded")
+            sess = self.sessions[order[cell]]
+            with tracing.span("ros2.ec.cell", op=op, cell=cell,
+                              tid=sess.label):
+                try:
+                    self._ec_retry(lambda: fn(sess))
+                except StorageError:
+                    # cell-level failure — target down OR the single-copy
+                    # media commit failed: either way the cell is
+                    # suspect, so ledger it (idempotent) and let rebuild
+                    # regenerate it from survivors; parity absorbs media
+                    # loss exactly like target loss
+                    with flock:
+                        failed.append(cell)
+                    self._ec_mark_dirty(oid, b, [cell], op=op)
+                    note_recovery(self._faults, "ec.cell_write_degraded")
 
         live = [(cell, fn) for cell, fn in jobs if cell not in down]
         quorum = min(len(live), k + 1)
-        if len(live) == 1:
-            run(*live[0])
-        elif pre_dirty:
-            # healing writes are synchronous: the ledger must only clear
-            # for cells that provably landed
-            pool = self._get_pool()
-            for f in [pool.submit(run, cell, fn) for cell, fn in live]:
-                f.result()
-        else:
-            pool = self._get_pool()
-            futs = [pool.submit(run, cell, fn) for cell, fn in live]
-            done = 0
-            for f in as_completed(futs):
-                f.result()
-                done += 1
-                if done >= quorum:
-                    break
-            rest = [f for f in futs if not f.done()]
-            if rest:
-                with self._map_lock:
-                    self._ec_pending.extend(rest)
+        with tracing.span("ros2.ec.fanout", op=op, cells=len(live)):
+            if len(live) == 1:
+                run(*live[0])
+            elif pre_dirty:
+                # healing writes are synchronous: the ledger must only
+                # clear for cells that provably landed
+                pool = self._get_pool()
+                for f in [pool.submit(run, cell, fn) for cell, fn in live]:
+                    f.result()
+            else:
+                pool = self._get_pool()
+                futs = [pool.submit(run, cell, fn) for cell, fn in live]
+                done = 0
+                for f in as_completed(futs):
+                    f.result()
+                    done += 1
+                    if done >= quorum:
+                        break
+                rest = [f for f in futs if not f.done()]
+                if rest:
+                    with self._map_lock:
+                        self._ec_pending.extend(rest)
         if pre_dirty:
             landed = [c for c, _fn in live if c not in failed]
             self._ec_clear_dirty(oid, b,
-                                 sorted(pre_dirty.intersection(landed)))
+                                 sorted(pre_dirty.intersection(landed)),
+                                 op=op)
         if len(set(down) | set(failed)) > p:
             raise StorageError(
                 f"ec({k},{p}) write lost {len(set(down) | set(failed))} "
                 f"cells of block {b} — stripe below k clean cells")
+        return "full"
 
     def _ec_write_block_delta(self, rs, oid: int, b: int, bo: int,
                               frag: np.ndarray, order: Sequence[int],
-                              touched: Sequence[int]) -> None:
+                              touched: Sequence[int], op: int = 0) -> None:
         """Delta-parity RMW: the small-write path that never reads the
         stripe. GF(256) linearity means P' = P XOR C[:, touched]·Δ with
         Δ = old XOR new over the media image of exactly the touched data
@@ -2352,13 +2406,13 @@ class _ClusterRouter(_SubmitReap):
         rebuild decodes the marked cell to that content)."""
         k, p, cs = self._ec
         ln = int(frag.size)
-        self._ec_drain()
+        self._ec_drain(op=op)
         # the caller judged the stripe clean BEFORE the drain — a
         # straggler that failed while draining has just ledgered a cell,
         # and delta-ing against its stale media bytes would bake the lie
         # into parity (reads decode-around the mark, so the corruption
         # would surface as wrong reconstructed bytes). Re-check.
-        if self._ec_read_dirty(oid, b):
+        if self._ec_read_dirty(oid, b, op=op):
             raise _EcDeltaUnavailable("stripe went dirty during drain")
         new_media = self._ec_media_image(frag, oid, b, bo)
         # one shared cell-coordinate window [w0, w1) covers every touched
@@ -2368,18 +2422,22 @@ class _ClusterRouter(_SubmitReap):
         deltas = np.zeros((len(touched), w1 - w0), np.uint8)
         fetched = 0
         try:
-            for r, i in enumerate(touched):
-                lo, hi = max(bo, i * cs), min(bo + ln, (i + 1) * cs)
-                old = self._ec_retry(
-                    lambda tid=order[i], lo=lo, hi=hi:
-                    self.sessions[tid].fetch_cell(oid, b, lo, hi - lo))
-                fetched += hi - lo
-                deltas[r, lo - i * cs - w0:hi - i * cs - w0] = \
-                    old ^ new_media[lo - bo:hi - bo]
+            with tracing.span("ros2.ec.old_fetch", op=op, bytes=ln):
+                for r, i in enumerate(touched):
+                    lo, hi = max(bo, i * cs), min(bo + ln, (i + 1) * cs)
+                    old = self._ec_retry(
+                        lambda tid=order[i], lo=lo, hi=hi:
+                        self.sessions[tid].fetch_cell(oid, b, lo, hi - lo))
+                    fetched += hi - lo
+                    deltas[r, lo - i * cs - w0:hi - i * cs - w0] = \
+                        old ^ new_media[lo - bo:hi - bo]
         except StorageError as e:
             raise _EcDeltaUnavailable(str(e)) from e
-        pdeltas = np.asarray(
-            rs.ec_parity_delta(k, p, list(touched), deltas))
+        with tracing.span("ros2.ec.parity.submit", op=op,
+                          bytes=deltas.size):
+            pending = rs.ec_parity_delta(k, p, list(touched), deltas)
+        with tracing.span("ros2.ec.parity.wait", op=op):
+            pdeltas = np.asarray(pending)
         jobs: List[Tuple[int, Callable[[_ServerIO], None]]] = []
         for i in touched:
             lo, hi = max(bo, i * cs), min(bo + ln, (i + 1) * cs)
@@ -2394,20 +2452,24 @@ class _ClusterRouter(_SubmitReap):
         flock = threading.Lock()
 
         def run(cell: int, fn) -> None:
-            try:
-                self._ec_retry(lambda: fn(self.sessions[order[cell]]))
-            except StorageError:
-                with flock:
-                    failed.append(cell)
-                self._ec_mark_dirty(oid, b, [cell])
-                note_recovery(self._faults, "ec.cell_write_degraded")
+            sess = self.sessions[order[cell]]
+            with tracing.span("ros2.ec.cell", op=op, cell=cell,
+                              tid=sess.label):
+                try:
+                    self._ec_retry(lambda: fn(sess))
+                except StorageError:
+                    with flock:
+                        failed.append(cell)
+                    self._ec_mark_dirty(oid, b, [cell], op=op)
+                    note_recovery(self._faults, "ec.cell_write_degraded")
 
-        if len(jobs) == 1:
-            run(*jobs[0])
-        else:
-            pool = self._get_pool()
-            for f in [pool.submit(run, cell, fn) for cell, fn in jobs]:
-                f.result()
+        with tracing.span("ros2.ec.fanout", op=op, cells=len(jobs)):
+            if len(jobs) == 1:
+                run(*jobs[0])
+            else:
+                pool = self._get_pool()
+                for f in [pool.submit(run, cell, fn) for cell, fn in jobs]:
+                    f.result()
         with self._map_lock:
             self.ec_delta_writes += 1
             self.ec_delta_bytes_saved += k * cs - fetched
@@ -3007,8 +3069,10 @@ class ROS2Client:
         legitimately take tens of seconds)."""
         if _timeout is None:
             _timeout = self.timeouts.dpu_wait_s
-        tag = self.dpu.submit(op, **args)
-        c = self.dpu.wait_tag(tag, timeout=_timeout)
+        with tracing.span("ros2.dpu.call", verb=op) as sp:
+            tag = self.dpu.submit(op, **args)
+            sp.set_metadata(tag=tag)
+            c = self.dpu.wait_tag(tag, timeout=_timeout)
         if not c.ok:
             raise IOError(c.error)
         return c.result

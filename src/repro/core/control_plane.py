@@ -34,6 +34,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core import tracing
 from repro.core.data_plane import AccessError, MemoryRegion, MemoryRegistry
 from repro.core.object_store import ObjectStore
 
@@ -111,6 +112,10 @@ class ControlPlane:
 
     # -- transport shim ------------------------------------------------------
     def rpc(self, method: str, **payload) -> Dict[str, Any]:
+        with tracing.span("ros2.control.rpc", method=method):
+            return self._rpc(method, **payload)
+
+    def _rpc(self, method: str, **payload) -> Dict[str, Any]:
         with self._lock:
             self.rpc_count += 1
             self.rpc_bytes += 64 + sum(

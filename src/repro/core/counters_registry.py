@@ -1,4 +1,5 @@
-"""Single declared registry of every data-path counter the stack emits.
+"""Single declared registry of every data-path counter and host span the
+stack emits.
 
 Motivation: counters are the observability contract of the whole fleet —
 the benchmark gates, the fault-soak assertions and the smoke gate all key
@@ -10,8 +11,8 @@ module closes that hole from both sides:
   * statically — the `counter` pass of ``python -m tools.analysis.lint``
     parses the literal sets below straight out of this file's AST and
     checks every ``note_recovery(...)`` literal, every ``.stats.<field>``
-    increment and every literal key built inside a
-    ``data_path_counters()`` body against them;
+    increment, every literal key built inside a ``data_path_counters()``
+    body and every ``tracing.span("<name>")`` against them;
 
   * at runtime — both ``data_path_counters()`` implementations (per-target
     session and cluster router) funnel their result through :func:`verify`
@@ -127,6 +128,34 @@ RECOVERY_PATHS = frozenset({
     "ec.delta_fallback",        # partial-stripe write degraded from the
     # delta-parity path to a full re-encode (touched/parity target down)
     "pipeline.read_retry",      # loader retried a transient storage stall
+})
+
+# Every host span the program emits (`tracing.span("<name>", ...)`, see
+# core/tracing.py). A span name in code but not here, or here but emitted
+# nowhere, is a lint failure: the benchmark's readers find spans by name.
+SPANS = frozenset({
+    "ros2.place.load",          # DeviceDirectSink.read_tensors, whole
+    "ros2.place.slot_wait",     # _acquire: free slot + previous tensors
+    "ros2.place.splice",        # pread_into_many into the ring slot
+    "ros2.place.put",           # per-dtype-group device_put dispatch
+    "ros2.place.carve",         # _carve_packed dispatch
+    "ros2.place.drain",         # the batch's final block_until_ready
+    "ros2.dpu.call",            # ROS2Client._dpu_call: doorbell + wait_tag
+    "ros2.dpu.exec",            # DPURuntime worker, around the handler
+    "ros2.router.sq_wait",      # _run_batch: per-target SQ slot acquire
+    "ros2.router.batch",        # _run_batch: the session calls
+    "ros2.target",              # _ServerIO data and cell verbs
+    "ros2.engine",              # DAOSObject update_many / fetch_scatter
+    "ros2.control.rpc",         # ControlPlane.rpc (compound included)
+    "ros2.ec.write",            # one stripe write, full or delta path
+    "ros2.ec.place",            # _ec_order
+    "ros2.ec.ledger",           # dirty-cell ledger read / mark / clear
+    "ros2.ec.drain",            # _ec_drain: join straggler cell writes
+    "ros2.ec.old_fetch",        # delta path: fetch of the old bytes
+    "ros2.ec.parity.submit",    # rs_parity call until it returns
+    "ros2.ec.parity.wait",      # np.asarray of its result
+    "ros2.ec.fanout",           # cell jobs: first submit to quorum / all
+    "ros2.ec.cell",             # one cell job, on its pool thread
 })
 
 # section name -> declared key set (the top-level shape of

@@ -49,6 +49,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 
 @partial(jax.jit, static_argnums=(1,))
 def _carve_packed(groups: Tuple[jax.Array, ...],
@@ -138,16 +140,19 @@ class DeviceDirectSink:
         self.close()
 
     # -- slot lifecycle ------------------------------------------------------
-    def _acquire(self) -> int:
-        with self._cv:
-            while not self._free:
-                self._cv.wait()
-            slot = self._free.pop()
-            pending = self._inflight.pop(slot, None)
-        if pending is not None:
-            # the slot's previous tensors must be materialized before its
-            # ring memory can be refilled (the DMA source is still live)
-            jax.block_until_ready(pending)
+    def _acquire(self, op: int = 0) -> int:
+        with tracing.span("ros2.place.slot_wait", op=op) as sp:
+            with self._cv:
+                while not self._free:
+                    self._cv.wait()
+                slot = self._free.pop()
+                pending = self._inflight.pop(slot, None)
+            sp.set_metadata(slot=slot)
+            if pending is not None:
+                # the slot's previous tensors must be materialized before
+                # its ring memory can be refilled (the DMA source is still
+                # live)
+                jax.block_until_ready(pending)
         return slot
 
     def _release(self, slot: int) -> None:
@@ -194,11 +199,20 @@ class DeviceDirectSink:
         path stays batched). Returns arrays in request order."""
         parsed = [(fd, off, tuple(shape), np.dtype(dtype))
                   for fd, off, shape, dtype in reqs]
+        total = 0
         for _fd, _off, shape, np_dtype in parsed:
             size = int(np.prod(shape)) * np_dtype.itemsize
             if size > self.slot_bytes:
                 raise ValueError(
                     f"tensor {size}B exceeds slot {self.slot_bytes}B")
+            total += size
+        op = tracing.next_op_id()
+        with tracing.span("ros2.place.load", op=op, bytes=total):
+            return self._place(parsed, sharding, op)
+
+    def _place(self, parsed, sharding, op: int) -> List[jax.Array]:
+        """`read_tensors` after its checks: pack, splice, put and carve
+        slot by slot, then wait for the whole batch."""
         out: List[Optional[jax.Array]] = [None] * len(parsed)
         i = 0
         while i < len(parsed):
@@ -216,21 +230,27 @@ class DeviceDirectSink:
                 dtypes.add(np_dtype)
                 i += 1
             groups, placed = _pack_slot(pack)
-            slot = self._acquire()          # blocks iff the slot's previous
+            slot = self._acquire(op)        # blocks iff the slot's previous
             try:                            # tensors are still in flight
                 base = slot * self.slot_bytes
-                self.client.pread_into_many(
-                    [(fd, size, off, base + pos)
-                     for _ix, fd, off, size, pos, *_rest in placed],
-                    self.ring)
+                with tracing.span("ros2.place.splice", op=op,
+                                  bytes=sum(p[3] for p in placed)):
+                    self.client.pread_into_many(
+                        [(fd, size, off, base + pos)
+                         for _ix, fd, off, size, pos, *_rest in placed],
+                        self.ring)
                 # one host->device DMA per dtype group, typed on the host
-                packed = tuple(
-                    jax.device_put(self.ring.buf[base + g0:base + g1]
-                                   .view(np_dtype))
-                    for np_dtype, g0, g1 in groups)
+                with tracing.span("ros2.place.put", op=op,
+                                  bytes=sum(g1 - g0 for _dt, g0, g1
+                                            in groups)):
+                    packed = tuple(
+                        jax.device_put(self.ring.buf[base + g0:base + g1]
+                                       .view(np_dtype))
+                        for np_dtype, g0, g1 in groups)
                 layout = tuple((g, start, shape)
                                for *_x, g, start, shape in placed)
-                carved = _carve_packed(packed, layout)
+                with tracing.span("ros2.place.carve", op=op):
+                    carved = _carve_packed(packed, layout)
                 for (ix, *_rest), arr in zip(placed, carved):
                     if sharding is not None:
                         arr = jax.device_put(arr, sharding)
@@ -249,7 +269,8 @@ class DeviceDirectSink:
                 self._release(slot)
         # the returned batch is fully materialized (callers may mutate or
         # re-read the files immediately)
-        jax.block_until_ready([a for a in out if a is not None])
+        with tracing.span("ros2.place.drain", op=op):
+            jax.block_until_ready([a for a in out if a is not None])
         return out
 
 

@@ -22,6 +22,7 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.faults import (DEFAULT_TIMEOUTS, FaultInjector, OpTimeout,
                                Timeouts, note_recovery)
 from repro.core.media import Device, checksum, make_nvme_array
@@ -319,10 +320,16 @@ class DAOSObject:
         Donated leases are pre-pinned once per planned replica on THIS
         thread, so a slot can never return to the ring while a background
         commit still sources from it."""
+        items = list(items)
+        with tracing.span("ros2.engine", verb="update",
+                          bytes=sum(_nbytes(it[3]) for it in items)):
+            return self._update_many(items, epoch, leases)
+
+    def _update_many(self, items: List[Tuple[str, str, int, bytes]],
+                     epoch: Optional[int], leases: Optional[Sequence]) -> int:
         cont = self.container
         store = cont.store
         epoch = cont.next_epoch() if epoch is None else epoch
-        items = list(items)
         leases = list(leases) if leases is not None else [None] * len(items)
         staged: List[tuple] = []
         for (dkey, akey, offset, data), lease in zip(items, leases):
@@ -619,6 +626,13 @@ class DAOSObject:
         (its device blocks reclaimed after the grace window), the read
         restarts on a fresh snapshot — the superseding extent is newer than
         ours, so the retry observes a consistent, more recent state."""
+        with tracing.span("ros2.engine", verb="fetch", bytes=size):
+            return self._fetch_scatter(dkey, akey, offset, size, dsts,
+                                       epoch, verify)
+
+    def _fetch_scatter(self, dkey: str, akey: str, offset: int, size: int,
+                       dsts: Sequence[Tuple[np.ndarray, int, int]],
+                       epoch: Optional[int], verify: bool) -> int:
         for attempt in range(8):
             with self._lock:
                 exts = list(self._extents.get((dkey, akey), ()))

@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.faults import DEFAULT_TIMEOUTS, OpTimeout, Timeouts
 
 N_ARM_CORES = 16
@@ -280,7 +281,9 @@ class DPURuntime:
                 return
             try:
                 fn = self._handlers[sqe.op]
-                res = fn(**sqe.args)
+                with tracing.span("ros2.dpu.exec", tag=sqe.tag,
+                                  verb=sqe.op):
+                    res = fn(**sqe.args)
                 self.cq.put(CQE(sqe.tag, True, res))
             # lint: allow(broad-except): not a swallow — the worker
             # CONVERTS any handler failure into an error CQE, so the

@@ -179,6 +179,40 @@ def test_counters_flags_undeclared_section_in_data_path_counters():
     assert "no_such_section" in msgs
 
 
+def test_counters_flags_undeclared_span_name():
+    bad = _lint("""
+        def write(self, op):
+            with tracing.span("ros2.ec.wirte", op=op):   # typo
+                pass
+            with tracing.span(self.name):                # not a literal
+                pass
+    """, passes=["counter"])
+    assert _rules(bad) == ["counter", "counter"]
+    msgs = " / ".join(f.msg for f in bad)
+    assert "ros2.ec.wirte" in msgs and "string literal" in msgs
+    clean = _lint("""
+        def write(self, op):
+            with tracing.span("ros2.ec.write", op=op):
+                pass
+    """, passes=["counter"])
+    assert clean == []
+
+
+def test_counters_finalize_flags_a_declared_span_never_emitted():
+    counters_pass._seen_paths.clear()
+    counters_pass._seen_spans.clear()
+    emitting_all_but_one = "\n".join(
+        f"tracing.span({name!r})"
+        for name in sorted(counters_pass.load_registry().spans)
+        if name != "ros2.ec.drain")
+    lint_source(emitting_all_but_one, passes=["counter"])
+    stale = [f for f in counters_pass.finalize([])
+             if "SPANS" in f.msg]
+    assert [("ros2.ec.drain" in f.msg) for f in stale] == [True]
+    lint_source("tracing.span('ros2.ec.drain')", passes=["counter"])
+    assert not [f for f in counters_pass.finalize([]) if "SPANS" in f.msg]
+
+
 def test_exceptions_flags_swallow_and_accepts_reraise_or_typed():
     bad = _lint("""
         def commit(self):
@@ -453,6 +487,7 @@ def test_leakwitness_thread_accounting_sees_repo_threads():
 
 def test_scoped_repo_lint_is_clean():
     counters_pass._seen_paths.clear()     # hermetic finalize sweep
+    counters_pass._seen_spans.clear()
     findings = lint_paths(scoped_files(repo_root()))
     assert findings == [], \
         "repo lint regressions:\n" + "\n".join(f.render()

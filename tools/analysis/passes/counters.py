@@ -11,11 +11,13 @@ scoped module:
   * every ``<obj>.stats.<field> += ...`` increment names a declared
     Stats field;
   * every literal section/key built inside a ``data_path_counters()``
-    body is declared under its section.
+    body is declared under its section;
+  * every ``tracing.span(<name>, ...)`` names a declared SPAN with a
+    string literal (the benchmark's readers find spans by name).
 
 ``finalize`` (full-repo runs only) closes the loop in the other
-direction: a RECOVERY_PATH declared but never emitted anywhere is a
-stale registry entry and is flagged too.
+direction: a RECOVERY_PATH or SPAN declared but never emitted anywhere
+is a stale registry entry and is flagged too.
 """
 from __future__ import annotations
 
@@ -36,11 +38,14 @@ REGISTRY_PATH: Optional[Path] = None
 class Registry:
     def __init__(self, counters: Dict[str, FrozenSet[str]],
                  recovery_paths: FrozenSet[str],
-                 recovery_line: int, path: str):
+                 recovery_line: int, path: str,
+                 spans: FrozenSet[str] = frozenset(), spans_line: int = 1):
         self.counters = counters
         self.recovery_paths = recovery_paths
         self.recovery_line = recovery_line
         self.path = path
+        self.spans = spans
+        self.spans_line = spans_line
         self.stats_keys = frozenset().union(*counters.values()) \
             if counters else frozenset()
 
@@ -59,7 +64,7 @@ def load_registry(root: Optional[Path] = None) -> Registry:
     tree = ast.parse(path.read_text(), filename=str(path))
     sets: Dict[str, FrozenSet[str]] = {}
     counters: Dict[str, FrozenSet[str]] = {}
-    recovery_line = 1
+    lines: Dict[str, int] = {}
     for node in tree.body:
         if not isinstance(node, (ast.Assign, ast.AnnAssign)):
             continue
@@ -76,15 +81,15 @@ def load_registry(root: Optional[Path] = None) -> Registry:
                         if isinstance(e, ast.Constant)
                         and isinstance(e.value, str)}
                 sets[name] = frozenset(lits)
-                if name == "RECOVERY_PATHS":
-                    recovery_line = node.lineno
+                lines[name] = node.lineno
         elif isinstance(value, ast.Dict) and name == "COUNTERS":
             for k, v in zip(value.keys, value.values):
                 if isinstance(k, ast.Constant) and isinstance(v, ast.Name) \
                         and v.id in sets:
                     counters[k.value] = sets[v.id]
     reg = Registry(counters, sets.get("RECOVERY_PATHS", frozenset()),
-                   recovery_line, str(path))
+                   lines.get("RECOVERY_PATHS", 1), str(path),
+                   sets.get("SPANS", frozenset()), lines.get("SPANS", 1))
     _cache[key] = reg
     return reg
 
@@ -98,6 +103,13 @@ def _recovery_literal(call: ast.Call) -> Optional[ast.Constant]:
         if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
             return arg
     return None
+
+
+def _span_call(call: ast.Call) -> bool:
+    """A ``tracing.span(...)`` call."""
+    f = call.func
+    return isinstance(f, ast.Attribute) and f.attr == "span" \
+        and isinstance(f.value, ast.Name) and f.value.id == "tracing"
 
 
 def _check_counters_fn(mod: Module, fn: ast.FunctionDef,
@@ -140,15 +152,32 @@ def _check_counters_fn(mod: Module, fn: ast.FunctionDef,
                         check_section(k.value, v, k.lineno)
 
 
-# note_recovery literals seen across the whole run (for finalize)
+# note_recovery and span literals seen across the whole run (finalize)
 _seen_paths: Set[str] = set()
+_seen_spans: Set[str] = set()
 
 
 def run(mod: Module) -> List[Finding]:
     reg = load_registry()
     out: List[Finding] = []
     for node in ast.walk(mod.tree):
-        if isinstance(node, ast.Call):
+        if isinstance(node, ast.Call) and _span_call(node):
+            arg = node.args[0] if node.args else None
+            if not (isinstance(arg, ast.Constant)
+                    and isinstance(arg.value, str)):
+                out.append(Finding(
+                    RULE, mod.path, node.lineno,
+                    "tracing.span name must be a string literal declared "
+                    "in counters_registry.SPANS"))
+                continue
+            _seen_spans.add(arg.value)
+            if arg.value not in reg.spans:
+                out.append(Finding(
+                    RULE, mod.path, node.lineno,
+                    f"span '{arg.value}' is not declared in "
+                    f"counters_registry.SPANS — a typo here ships a span "
+                    f"no reader finds"))
+        elif isinstance(node, ast.Call):
             lit = _recovery_literal(node)
             if lit is not None:
                 _seen_paths.add(lit.value)
@@ -175,11 +204,17 @@ def run(mod: Module) -> List[Finding]:
 
 
 def finalize(mods: List[Module]) -> List[Finding]:
-    """Full-repo sweep: declared recovery paths nobody emits are stale."""
+    """Full-repo sweep: declared recovery paths and spans nobody emits
+    are stale."""
     reg = load_registry()
-    stale = reg.recovery_paths - _seen_paths
-    return [Finding(
+    out = [Finding(
         RULE, reg.path, reg.recovery_line,
         f"RECOVERY_PATHS entry '{p}' is emitted nowhere in the scoped "
         f"modules — stale registry entries hide real coverage gaps")
-        for p in sorted(stale)]
+        for p in sorted(reg.recovery_paths - _seen_paths)]
+    out += [Finding(
+        RULE, reg.path, reg.spans_line,
+        f"SPANS entry '{s}' is emitted nowhere in the scoped modules — "
+        f"a reader of it would find nothing")
+        for s in sorted(reg.spans - _seen_spans)]
+    return out
