@@ -2714,6 +2714,7 @@ class _ClusterRouter(_SubmitReap):
                 "placement_cache_hits": self.placement_cache_hits,
             }
             if self._ec is not None:
+                from repro.kernels.rs_parity import ops as rs
                 out["ec"] = {
                     "k": self._ec[0], "p": self._ec[1],
                     "degraded_reads": self.ec_degraded_reads,
@@ -2724,6 +2725,9 @@ class _ClusterRouter(_SubmitReap):
                     "delta_writes": self.ec_delta_writes,
                     "delta_bytes_saved": self.ec_delta_bytes_saved,
                     "delta_fallbacks": self.ec_delta_fallbacks,
+                    # process-wide: the cache is shared by every caller
+                    "parity_coeff_hits": rs.coeff_cache.hits,
+                    "parity_coeff_misses": rs.coeff_cache.misses,
                 }
         return counters_registry.verify(out)
 

@@ -98,6 +98,7 @@ CLUSTER_KEYS = frozenset({
 EC_KEYS = frozenset({
     "k", "p", "degraded_reads", "reconstructions", "rebuilt_cells",
     "delta_writes", "delta_bytes_saved", "delta_fallbacks",
+    "parity_coeff_hits", "parity_coeff_misses",
 })
 
 FAULTS_KEYS = frozenset({

@@ -3,20 +3,66 @@
 `ec_encode` / `ec_decode` are the two legs the data path uses: the write
 fan-out encodes k data cells into p parity cells, and degraded reads /
 rebuild reconstruct missing data cells from any k survivors. Coefficient
-matrices come from the numpy oracle (ref.py — table math is cheap at
-(k, p) scale) and are passed traced, so one compilation per (m, s, tile)
-shape serves every stripe and every survivor subset.
+matrices come from the numpy oracle (ref.py) and are passed traced, so
+one compilation per (m, s, tile) shape serves every stripe and every
+survivor subset.
+
+Each call is one dispatch with one input transfer. The coefficient
+matrix of a (leg, k, p, subset) key is put on the device once and kept
+(`coeff_cache`); host cell rows go to the jitted program as they are,
+so the jit's own argument handling makes their one H2D; and the result's
+D2H is queued at dispatch (`copy_to_host_async`), so the caller's
+`np.asarray` waits for a copy already in flight.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+import threading
+from collections import OrderedDict
+from typing import Callable, Hashable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.rs_parity import kernel as K
 from repro.kernels.rs_parity import ref
+
+
+class CoefficientCache:
+    """Device-resident u8 coefficient matrices, built once per key and
+    device. Bounded (least recently used goes first) and thread-safe;
+    `hits` and `misses` count its engagement for the whole process.
+    ec(4,2) needs 1 encode, 15 delta and a few dozen decode matrices of a
+    few bytes each; the bound only stops an unbounded set of keys."""
+
+    CAPACITY = 1024
+
+    def __init__(self) -> None:
+        self.hits = 0
+        self.misses = 0
+        self._lock = threading.Lock()
+        self._mats: "OrderedDict[tuple, jax.Array]" = OrderedDict()
+
+    def get(self, key: Hashable, device: Optional[jax.Device],
+            build: Callable[[], np.ndarray]) -> jax.Array:
+        full = (key, device)
+        with self._lock:
+            mat = self._mats.get(full)
+            if mat is not None:
+                self._mats.move_to_end(full)
+                self.hits += 1
+                return mat
+            self.misses += 1
+        mat = jax.device_put(np.asarray(build(), np.uint8), device)
+        with self._lock:
+            self._mats[full] = mat
+            while len(self._mats) > self.CAPACITY:
+                self._mats.popitem(last=False)
+        return mat
+
+
+coeff_cache = CoefficientCache()
 
 
 def _interpret_default() -> bool:
@@ -36,13 +82,32 @@ def _gf_matmul(mat: jax.Array, cells: jax.Array, m: int, s: int, tile: int,
         jnp.uint8)
 
 
-def gf_matmul(mat, cells, *, tile: int = K.DEFAULT_TILE,
-              interpret: Optional[bool] = None) -> jax.Array:
-    """(m, s) u8 GF coefficient matrix times (s, L) u8 cell rows."""
+def _as_u8(a):
+    """`a` as the jitted call takes it: a jax.Array passes through (cast
+    to u8 if it is not), anything else becomes a C-contiguous u8 ndarray,
+    copied only where it is not one already."""
+    if isinstance(a, jax.Array):
+        return a if a.dtype == jnp.uint8 else a.astype(jnp.uint8)
+    if isinstance(a, np.ndarray) and a.dtype == np.uint8 \
+            and a.flags.c_contiguous:
+        return a
+    return np.ascontiguousarray(a, np.uint8)
+
+
+def _device_of(cells) -> Optional[jax.Device]:
+    """The device the call runs on: a single-device jax.Array's own, else
+    the default device (where the jit puts host arguments)."""
+    if isinstance(cells, jax.Array):
+        devs = cells.devices()
+        return next(iter(devs)) if len(devs) == 1 else None
+    dflt = jax.config.jax_default_device
+    return dflt if isinstance(dflt, jax.Device) else jax.devices(dflt)[0]
+
+
+def _matmul(mat, cells, tile: int, interpret: Optional[bool]) -> jax.Array:
+    """One dispatch of `_gf_matmul` on u8 inputs, its D2H queued."""
     if interpret is None:
         interpret = _interpret_default()
-    mat = jnp.asarray(mat, jnp.uint8)
-    cells = jnp.asarray(cells, jnp.uint8)
     m, s = mat.shape
     if cells.shape[0] != s:
         raise ValueError(f"matrix is {mat.shape} but got {cells.shape[0]} "
@@ -50,7 +115,15 @@ def gf_matmul(mat, cells, *, tile: int = K.DEFAULT_TILE,
     if m == 0 or cells.shape[1] == 0:
         return jnp.zeros((m, cells.shape[1]), jnp.uint8)
     eff = _effective_tile(cells.shape[1], tile, bool(interpret))
-    return _gf_matmul(mat, cells, m, s, eff, bool(interpret))
+    out = _gf_matmul(mat, cells, m, s, eff, bool(interpret))
+    out.copy_to_host_async()
+    return out
+
+
+def gf_matmul(mat, cells, *, tile: int = K.DEFAULT_TILE,
+              interpret: Optional[bool] = None) -> jax.Array:
+    """(m, s) u8 GF coefficient matrix times (s, L) u8 cell rows."""
+    return _matmul(_as_u8(mat), _as_u8(cells), tile, interpret)
 
 
 def _effective_tile(n: int, tile: int, interpret: bool) -> int:
@@ -67,9 +140,11 @@ def _effective_tile(n: int, tile: int, interpret: bool) -> int:
 def ec_encode(cells, p: int, *, tile: int = K.DEFAULT_TILE,
               interpret: Optional[bool] = None) -> jax.Array:
     """(k, L) u8 data cells -> (p, L) u8 Reed-Solomon parity cells."""
-    cells = jnp.asarray(cells, jnp.uint8)
-    return gf_matmul(ref.cauchy_matrix(cells.shape[0], p), cells,
-                     tile=tile, interpret=interpret)
+    cells = _as_u8(cells)
+    k = cells.shape[0]
+    mat = coeff_cache.get(("encode", k, p), _device_of(cells),
+                          lambda: ref.cauchy_matrix(k, p))
+    return _matmul(mat, cells, tile, interpret)
 
 
 def ec_parity_delta(k: int, p: int, cells_idx: Sequence[int], deltas, *,
@@ -86,15 +161,17 @@ def ec_parity_delta(k: int, p: int, cells_idx: Sequence[int], deltas, *,
     `xor_apply` op) — bit-exact against a full re-encode (property-
     tested vs the ref.py oracle). Same Pallas tile kernel as `ec_encode`
     with the Cauchy column submatrix, interpret fallback included."""
-    idx = list(cells_idx)
+    idx = tuple(int(i) for i in cells_idx)
     if any(i < 0 or i >= k for i in idx):
-        raise ValueError(f"touched cells {idx} outside data range 0..{k - 1}")
-    deltas = jnp.asarray(deltas, jnp.uint8)
+        raise ValueError(f"touched cells {list(idx)} outside data range "
+                         f"0..{k - 1}")
+    deltas = _as_u8(deltas)
     if deltas.shape[0] != len(idx):
         raise ValueError(
             f"{deltas.shape[0]} delta rows for {len(idx)} touched cells")
-    return gf_matmul(ref.cauchy_matrix(k, p)[:, idx], deltas,
-                     tile=tile, interpret=interpret)
+    mat = coeff_cache.get(("delta", k, p, idx), _device_of(deltas),
+                          lambda: ref.cauchy_matrix(k, p)[:, list(idx)])
+    return _matmul(mat, deltas, tile, interpret)
 
 
 def ec_decode(survivors, present: Sequence[int], k: int, p: int,
@@ -106,10 +183,14 @@ def ec_decode(survivors, present: Sequence[int], k: int, p: int,
     survivors: (k, L) u8 rows ordered as `present` (stripe indices 0..k+p-1,
     parity cells are k..). Returns (len(missing), L) u8 — by default every
     data cell not among the survivors, ascending."""
+    present = tuple(int(i) for i in present)
     if missing is None:
-        missing = [i for i in range(k) if i not in list(present)]
-    survivors = jnp.asarray(survivors, jnp.uint8)
+        missing = [i for i in range(k) if i not in present]
+    missing = tuple(int(i) for i in missing)
+    survivors = _as_u8(survivors)
     if not missing:
         return jnp.zeros((0, survivors.shape[1]), jnp.uint8)
-    return gf_matmul(ref.decode_matrix(k, p, present, missing), survivors,
-                     tile=tile, interpret=interpret)
+    mat = coeff_cache.get(
+        ("decode", k, p, present, missing), _device_of(survivors),
+        lambda: ref.decode_matrix(k, p, present, missing))
+    return _matmul(mat, survivors, tile, interpret)

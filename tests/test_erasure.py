@@ -365,6 +365,31 @@ def test_ec_delta_rmw_partial_write_counted_and_bit_exact(inline_encryption):
     c.close()
 
 
+def test_parity_coefficient_cache_counts_hits_in_router_counters():
+    """After one warm delta write, N more writes into the same data cell
+    reuse its device-resident coefficient matrix: `parity_coeff_hits`
+    rises by N, `parity_coeff_misses` stays, and both keys pass the
+    counter registry."""
+    from repro.core import counters_registry
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    fd = c.open("/f", create=True)
+    c.pwrite(fd, _payload(BLOCK, 90), 0)
+    c.pwrite(fd, _payload(4096, 91), 8192)             # warm: cell 0
+    before = c.io.data_path_counters()
+    n = 5
+    for i in range(n):
+        c.pwrite(fd, _payload(4096, 92 + i), 4096 * (i % 3))
+    after = c.io.data_path_counters()
+    counters_registry.verify(after)
+    assert after["ec"]["delta_writes"] - before["ec"]["delta_writes"] == n
+    assert after["ec"]["parity_coeff_hits"] \
+        - before["ec"]["parity_coeff_hits"] == n
+    assert after["ec"]["parity_coeff_misses"] \
+        == before["ec"]["parity_coeff_misses"]
+    c.close()
+
+
 def test_ec_delta_falls_back_when_parity_target_down():
     """The delta path needs every touched-data and parity target UP (it
     xors in place; there is no quorum to hide behind). With a parity

@@ -141,3 +141,67 @@ def test_delta_parity_matches_full_reencode(case):
                                   rs_parity_delta_np(k, p, touched, deltas))
     np.testing.assert_array_equal(parity ^ pdelta,
                                   rs_encode_np(new_cells, p))
+
+
+def _cache_key_schedule():
+    """Every call of the cross-key test: every touched subset of ec(4,2)
+    through the delta leg, encode at ec(4,2) and ec(8,3), and ten
+    decode (present, missing) sets, twice over, the second round in
+    another order so each key is hit after others."""
+    from itertools import combinations
+    calls = [("delta", 4, 2, list(t)) for r in range(1, 5)
+             for t in combinations(range(4), r)]
+    calls += [("encode", 4, 2, None), ("encode", 8, 3, None)]
+    # the same missing cells from other survivors, or from the same
+    # survivors in another row order, need another matrix
+    calls += [("decode", 4, 2, (present, missing)) for present, missing in [
+        ([1, 2, 3, 4], [0]), ([1, 2, 3, 5], [0]), ([0, 2, 3, 5], [1]),
+        ([2, 0, 3, 5], [1]), ([0, 1, 2, 5], [3]), ([2, 3, 4, 5], [0, 1]),
+        ([0, 3, 4, 5], [1, 2]), ([3, 0, 5, 4], [1, 2]),
+        ([1, 2, 4, 5], [0, 3]), ([1, 2, 4, 5], [3, 0])]]
+    order = np.random.default_rng(14).permutation(len(calls))
+    return calls + [calls[i] for i in order]
+
+
+def _as_form(rows: np.ndarray, form: str):
+    """The same u8 rows as a C-contiguous ndarray, a jax.Array, or a
+    non-contiguous numpy view (every other byte of a wider buffer)."""
+    if form == "jax":
+        import jax.numpy as jnp
+        return jnp.asarray(rows)
+    if form == "view":
+        wide = np.zeros((rows.shape[0], 2 * rows.shape[1]), np.uint8)
+        wide[:, ::2] = rows
+        view = wide[:, ::2]
+        assert not view.flags.c_contiguous
+        return view
+    return rows
+
+
+@pytest.mark.parametrize("form", ["numpy", "jax", "view"])
+def test_coefficient_cache_keys_stay_bit_exact(form):
+    """The device-resident coefficient cache hands each call the matrix
+    of its own (leg, k, p, subset): interleaved delta, encode and decode
+    calls at ec(4,2) and ec(8,3) all equal the ref.py oracle, whatever
+    form the cell rows arrive in. A cache keyed too coarsely would give
+    one touched subset or survivor set another's matrix."""
+    rng = np.random.default_rng(1400)
+    size = 131
+    for leg, k, p, arg in _cache_key_schedule():
+        if leg == "delta":
+            rows = rng.integers(0, 256, (len(arg), size), dtype=np.uint8)
+            got = ec_parity_delta(k, p, arg, _as_form(rows, form))
+            want = rs_parity_delta_np(k, p, arg, rows)
+        elif leg == "encode":
+            rows = rng.integers(0, 256, (k, size), dtype=np.uint8)
+            got = ec_encode(_as_form(rows, form), p)
+            want = rs_encode_np(rows, p)
+        else:
+            present, missing = arg
+            cells = rng.integers(0, 256, (k, size), dtype=np.uint8)
+            stripe = np.concatenate([cells, rs_encode_np(cells, p)])
+            got = ec_decode(_as_form(stripe[present], form), present, k, p,
+                            missing)
+            want = cells[missing]
+        np.testing.assert_array_equal(np.asarray(got), want,
+                                      err_msg=f"{leg} ec({k},{p}) {arg}")
