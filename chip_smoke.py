@@ -361,7 +361,8 @@ def sharded_placement_phase(client, host_leaves, mesh) -> None:
     the host reference under the same sharding, shard by shard."""
     sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
     reqs = store_leaves(client, "/weights", host_leaves)
-    with DeviceDirectSink(client, slot_bytes=SLOT_BYTES, n_slots=2) as sink:
+    with DeviceDirectSink(client, slot_bytes=SLOT_BYTES, n_slots=2,
+                          devices=list(mesh.devices.flat)) as sink:
         got = sink.read_tensors(reqs, sharding=sharding)
     for i, (g, w) in enumerate(zip(got, host_leaves)):
         want = jax.device_put(w, sharding)

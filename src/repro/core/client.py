@@ -120,6 +120,28 @@ def merge_counters(dicts: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     return out
 
 
+class PlacementCounters:
+    """Bytes device-direct placement (core/device_direct.py) spliced into
+    each device's ring slots and landed in each device's HBM, keyed by
+    device id ("default": JAX's default device). A sink's per-device
+    pipelines note them from their own threads."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._spliced: Dict[str, int] = {}
+        self._landed: Dict[str, int] = {}
+
+    def note(self, device: str, spliced: int = 0, landed: int = 0) -> None:
+        with self._lock:
+            self._spliced[device] = self._spliced.get(device, 0) + spliced
+            self._landed[device] = self._landed.get(device, 0) + landed
+
+    def counters(self) -> Dict[str, Dict[str, int]]:
+        with self._lock:
+            return {"spliced_bytes": dict(self._spliced),
+                    "landed_bytes": dict(self._landed)}
+
+
 class SlotLease:
     """Lease on a DONATED staging-ring slot.
 
@@ -736,6 +758,7 @@ class _ServerIO(_SubmitReap):
         self.xport.faults = faults
         # submit/reap state: shared CQ + this target's submission ring
         self._init_submit(io_depth, timeouts)
+        self.placement = PlacementCounters()
         self.sq = _SubmissionRing(self.io_depth, timeouts)
         # capability exchange happens in the owner's bring-up compound
         # (ROS2Client) — attach_session hands us the session + staging rkey
@@ -887,6 +910,7 @@ class _ServerIO(_SubmitReap):
                         "rpc_bytes": self.cp.rpc_bytes,
                         "compound_ops": self.cp.compound_ops,
                         "invalidations_sent": self.cp.invalidations_sent},
+            "placement": self.placement.counters(),
         }
         if self.cache is not None:
             out["meta_cache"] = asdict(self.cache.stats)
@@ -1691,6 +1715,7 @@ class _ClusterRouter(_SubmitReap):
         # target (a coalesced per-target run takes ONE slot — fragments
         # inside it still ride a single SG/placement verb)
         self._init_submit(io_depth, timeouts)
+        self.placement = PlacementCounters()
         self._rings: Dict[int, _SubmissionRing] = {}
         self._rings_lock = threading.Lock()
 
@@ -2697,6 +2722,8 @@ class _ClusterRouter(_SubmitReap):
         # with the per-session CQs: ONE fleet view of submit/reap traffic
         out["cq"] = merge_counters([out["cq"], self.cq.counters()])
         out["control"] = per[0]["control"]
+        # a sink notes placement on its client's I/O object: the router
+        out["placement"] = self.placement.counters()
         # the injector is ONE fleet-shared object: report it once (summing
         # per-session copies would multiply every count by n_targets)
         for k in ("meta_cache", "crypto", "faults"):

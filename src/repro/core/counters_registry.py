@@ -65,6 +65,10 @@ DIRECT_KEYS = frozenset({
     "reads", "bytes", "device_puts", "batches",
 })
 
+# Device-direct placement per device (PlacementCounters in client.py):
+# each key maps a device id ("default": JAX's default device) to bytes.
+PLACEMENT_KEYS = frozenset({"spliced_bytes", "landed_bytes"})
+
 # Sections built as literal dicts in client.py.
 MEDIA_KEYS = frozenset({
     "host_copy_bytes", "donated_bytes", "writeback_bytes",
@@ -141,6 +145,8 @@ SPANS = frozenset({
     "ros2.place.put",           # per-dtype-group device_put dispatch
     "ros2.place.carve",         # _carve_packed dispatch
     "ros2.place.drain",         # the batch's final block_until_ready
+    "ros2.place.shard",         # one device's share of a slot, splice..carve
+    "ros2.place.exchange",      # dispatch of the strided shards' exchange
     "ros2.dpu.call",            # ROS2Client._dpu_call: doorbell + wait_tag
     "ros2.dpu.exec",            # DPURuntime worker, around the handler
     "ros2.router.sq_wait",      # _run_batch: per-target SQ slot acquire
@@ -176,6 +182,7 @@ COUNTERS: Dict[str, FrozenSet[str]] = {
     "cluster": CLUSTER_KEYS,
     "ec": EC_KEYS,
     "device_direct": DIRECT_KEYS,
+    "placement": PLACEMENT_KEYS,
 }
 
 
