@@ -67,12 +67,13 @@ same calibrated model the paper-figure benchmarks use.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
 from collections import OrderedDict
 from concurrent.futures import (CancelledError, ThreadPoolExecutor,
-                                as_completed)
+                                as_completed, wait)
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -1689,6 +1690,10 @@ class _ClusterRouter(_SubmitReap):
         # re-encode (touched/parity target down, or old-bytes fetch lost
         # its target mid-op)
         self._ec_pending: List = []   # straggler cell writes in flight
+        self.ec_parity_overlap_writes = 0  # writes with a cell in flight
+        # before their parity wait
+        self.ec_parity_serial_writes = 0   # writes whose cells all waited
+        # for the parity (a data cell's target down)
         self._sid: Optional[int] = None
         self.cache = None
         self._map_lock = threading.Lock()
@@ -2259,14 +2264,137 @@ class _ClusterRouter(_SubmitReap):
             pos += ln
         return total
 
+    def _ec_fanout(self, oid: int, b: int, order: Sequence[int],
+                   up: Dict[int, bool], free: Sequence[Tuple[int, Callable]],
+                   needs: Sequence[Tuple[int, Callable]],
+                   dispatch: Callable[[], Any], nbytes: int,
+                   quorum: Optional[int], clears: Sequence[int] = (),
+                   op: int = 0) -> List[int]:
+        """One stripe's cell fan-out around its parity call. `free` are
+        the (cell, fn(session)) jobs that need no parity, `needs` the
+        (cell, fn(session, parity_rows)) jobs that do; `dispatch()`
+        starts the parity call (`nbytes` of input) and returns its
+        pending result. Returns the cells that failed (each ledgered).
+
+        The free cells go to the pool first, then the op thread
+        dispatches the parity and hands out the parity jobs: the first
+        of them to run waits for the rows, and the others share them.
+        So the data cells move while the device computes, and a parity
+        cell starts the moment its rows are back. A job waits only on
+        the device, never on another job, so a full pool cannot starve
+        the parity. Waits for min(cells, quorum) completions, the rest
+        left in `_ec_pending`, or for every cell when `quorum` is None;
+        marks in `clears` are cleared for the cells that landed.
+
+        Data may now land before its parity exists, so a parity call
+        that raises costs all p parity cells: the cells handed out are
+        joined, the p parity cells ledgered dirty (and `clears` cleared
+        for the data that landed, which agrees with the new image), and
+        the write fails. That leaves at most p dirty cells only while no
+        data cell is dirty or unreachable, so when a data cell's target
+        is down the parity comes back before any cell moves."""
+        k, p, _cs = self._ec
+        failed: List[int] = []
+        flock = threading.Lock()
+        parity: List = []        # [rows], or [the error the call raised]
+        plock = threading.Lock()
+        pending = None
+
+        def run(cell: int, fn, *args) -> None:
+            sess = self.sessions[order[cell]]
+            with tracing.span("ros2.ec.cell", op=op, cell=cell,
+                              tid=sess.label):
+                try:
+                    self._ec_retry(lambda: fn(sess, *args))
+                except StorageError:
+                    # cell-level failure — target down OR the single-copy
+                    # media commit failed: either way the cell is
+                    # suspect, so ledger it (idempotent) and let rebuild
+                    # regenerate it from survivors; parity absorbs media
+                    # loss exactly like target loss
+                    with flock:
+                        failed.append(cell)
+                    self._ec_mark_dirty(oid, b, [cell], op=op)
+                    note_recovery(self._faults, "ec.cell_write_degraded")
+
+        def rows():
+            with plock:
+                if not parity:
+                    try:
+                        with tracing.span("ros2.ec.parity.wait", op=op):
+                            parity.append(np.asarray(pending))
+                    except Exception as e:  # lint: allow(broad-except): kept
+                        # for the op thread, which fails the write with it
+                        parity.append(e)
+            return parity[0]
+
+        def run_parity(cell: int, fn) -> None:
+            par = rows()
+            if not isinstance(par, Exception):
+                run(cell, fn, par)
+
+        def clear_landed(cells) -> None:
+            landed = {c for c in cells if c not in failed}
+            self._ec_clear_dirty(oid, b, sorted(landed.intersection(clears)),
+                                 op=op)
+
+        early = bool(free) and all(up.get(order[c]) for c in range(k))
+        with self._map_lock:
+            if early:
+                self.ec_parity_overlap_writes += 1
+            else:
+                self.ec_parity_serial_writes += 1
+        pool = self._get_pool()
+        cells = [c for c, _fn in free] + [c for c, _fn in needs]
+        futs: List = []
+        with contextlib.ExitStack() as fan:
+            if early:
+                fan.enter_context(tracing.span("ros2.ec.fanout", op=op,
+                                               cells=len(cells)))
+                futs = [pool.submit(run, c, fn) for c, fn in free]
+            try:
+                with tracing.span("ros2.ec.parity.submit", op=op,
+                                  bytes=nbytes):
+                    pending = dispatch()
+            except Exception as e:  # lint: allow(broad-except): kept
+                parity.append(e)    # and raised below, as the wait's
+            if not early and not isinstance(rows(), Exception):
+                fan.enter_context(tracing.span("ros2.ec.fanout", op=op,
+                                               cells=len(cells)))
+                futs = [pool.submit(run, c, fn) for c, fn in free]
+            futs += [pool.submit(run_parity, c, fn) for c, fn in needs]
+            # at most k free jobs, so a quorum of k+1 holds a parity job:
+            # the parity's outcome is known once the quorum is in
+            n = len(futs) if quorum is None else min(len(futs), quorum)
+            for i, f in enumerate(as_completed(futs)):
+                f.result()
+                if i + 1 >= n:
+                    break
+            if parity and isinstance(parity[0], Exception):
+                wait(futs)
+                if early:
+                    self._ec_mark_dirty(oid, b, list(range(k, k + p)),
+                                        op=op)
+                    clear_landed(c for c, _fn in free)
+                raise StorageError(f"ec parity for block {b} failed: "
+                                   f"{parity[0]!r}") from parity[0]
+            rest = [f for f in futs if not f.done()]
+            if rest:
+                with self._map_lock:
+                    self._ec_pending.extend(rest)
+        if quorum is None:
+            clear_landed(cells)
+        return failed
+
     def _ec_write_block(self, rs, oid: int, b: int, bo: int,
                         frag: np.ndarray, op: int = 0) -> str:
-        """One stripe's write: parity over the zero-padded full block in
-        the media domain (partial writes read-modify-write the stripe
-        image first), then a parallel fan-out of the touched data cells
-        (full session writev path — staging, transport, inline crypto)
-        and the p parity cells (raw cell plane). Foreground returns once
-        min(jobs, k+1) cells land; stragglers finish in background.
+        """One stripe's write: a parallel fan-out of the touched data
+        cells (full session writev path — staging, transport, inline
+        crypto) while the parity over the zero-padded full block in the
+        media domain computes (partial writes read-modify-write the
+        stripe image first), then the p parity cells (raw cell plane).
+        Foreground returns once min(jobs, k+1) cells land; stragglers
+        finish in background.
         Cells on down targets are dropped and marked dirty — more than p
         of them and the stripe would go below k clean cells, which is a
         hard error BEFORE any byte moves.
@@ -2279,7 +2407,9 @@ class _ClusterRouter(_SubmitReap):
         the new version (the RMW image reconstructs their true content),
         clearing the ledger for everything that lands. After any write,
         the dirty set is exactly {cells on down targets} ∪ {cells that
-        failed THIS write} — bounded by the pre-checks below.
+        failed THIS write} — bounded by the pre-checks below — plus the
+        p parity cells when the parity call itself failed after the data
+        cells moved (`_ec_fanout`).
 
         DELTA-PARITY RMW: a partial write to a CLEAN stripe whose touched
         data + parity targets are all up takes `_ec_write_block_delta`
@@ -2316,31 +2446,25 @@ class _ClusterRouter(_SubmitReap):
         else:
             media = self._ec_read_media_block(rs, oid, b)
             media[bo:bo + ln] = self._ec_media_image(frag, oid, b, bo)
-        with tracing.span("ros2.ec.parity.submit", op=op,
-                          bytes=media.size):
-            pending = rs.ec_encode(media.reshape(k, cs), p)
-        with tracing.span("ros2.ec.parity.wait", op=op):
-            parity = np.asarray(pending)
-        jobs: List[Tuple[int, Callable[[_ServerIO], None]]] = []
         touched = set(range(bo // cs, (bo + ln - 1) // cs + 1))
+        free: List[Tuple[int, Callable]] = []
         for i in sorted(touched):
             lo, hi = max(bo, i * cs), min(bo + ln, (i + 1) * cs)
             sub = frag[lo - bo:hi - bo]
-            jobs.append((i, lambda s, fo=b * BLOCK + lo, sub=sub:
+            free.append((i, lambda s, fo=b * BLOCK + lo, sub=sub:
                          s.writev(oid, fo, [sub])))
-        for j in range(p):
-            jobs.append((k + j, lambda s, co=(k + j) * cs, pay=parity[j]:
-                         s.update_cell(oid, b, co, pay)))
         # stale data cells neither touched nor parity: rewrite their
         # reconstructed media bytes straight onto the cell plane
         heal = pre_dirty - touched - set(range(k, k + p))
         for i in sorted(heal):
             pay = media[i * cs:(i + 1) * cs]
-            jobs.append((i, lambda s, co=i * cs, pay=pay:
+            free.append((i, lambda s, co=i * cs, pay=pay:
                          s.update_cell(oid, b, co, pay)))
+        needs = [(k + j, lambda s, par, co=(k + j) * cs, j=j:
+                  s.update_cell(oid, b, co, par[j])) for j in range(p)]
         with self._map_lock:
             up = dict(self._up)
-        down = [cell for cell, _fn in jobs if not up.get(order[cell])]
+        down = [cell for cell, _fn in free + needs if not up.get(order[cell])]
         stale_down = {c for c in pre_dirty if not up.get(order[c])}
         if len(set(down) | stale_down) > p:
             raise StorageError(
@@ -2349,56 +2473,14 @@ class _ClusterRouter(_SubmitReap):
                 "— stripe would fall below k clean cells")
         if down:
             self._ec_mark_dirty(oid, b, down, op=op)
-
-        failed: List[int] = []
-        flock = threading.Lock()
-
-        def run(cell: int, fn) -> None:
-            sess = self.sessions[order[cell]]
-            with tracing.span("ros2.ec.cell", op=op, cell=cell,
-                              tid=sess.label):
-                try:
-                    self._ec_retry(lambda: fn(sess))
-                except StorageError:
-                    # cell-level failure — target down OR the single-copy
-                    # media commit failed: either way the cell is
-                    # suspect, so ledger it (idempotent) and let rebuild
-                    # regenerate it from survivors; parity absorbs media
-                    # loss exactly like target loss
-                    with flock:
-                        failed.append(cell)
-                    self._ec_mark_dirty(oid, b, [cell], op=op)
-                    note_recovery(self._faults, "ec.cell_write_degraded")
-
-        live = [(cell, fn) for cell, fn in jobs if cell not in down]
-        quorum = min(len(live), k + 1)
-        with tracing.span("ros2.ec.fanout", op=op, cells=len(live)):
-            if len(live) == 1:
-                run(*live[0])
-            elif pre_dirty:
-                # healing writes are synchronous: the ledger must only
-                # clear for cells that provably landed
-                pool = self._get_pool()
-                for f in [pool.submit(run, cell, fn) for cell, fn in live]:
-                    f.result()
-            else:
-                pool = self._get_pool()
-                futs = [pool.submit(run, cell, fn) for cell, fn in live]
-                done = 0
-                for f in as_completed(futs):
-                    f.result()
-                    done += 1
-                    if done >= quorum:
-                        break
-                rest = [f for f in futs if not f.done()]
-                if rest:
-                    with self._map_lock:
-                        self._ec_pending.extend(rest)
-        if pre_dirty:
-            landed = [c for c, _fn in live if c not in failed]
-            self._ec_clear_dirty(oid, b,
-                                 sorted(pre_dirty.intersection(landed)),
-                                 op=op)
+        # healing writes wait for every cell: the ledger must only clear
+        # for cells that provably landed
+        failed = self._ec_fanout(
+            oid, b, order, up,
+            [job for job in free if job[0] not in down],
+            [job for job in needs if job[0] not in down],
+            lambda: rs.ec_encode(media.reshape(k, cs), p), media.size,
+            quorum=None if pre_dirty else k + 1, clears=pre_dirty, op=op)
         if len(set(down) | set(failed)) > p:
             raise StorageError(
                 f"ec({k},{p}) write lost {len(set(down) | set(failed))} "
@@ -2425,8 +2507,9 @@ class _ClusterRouter(_SubmitReap):
         xor'd extent with a stale base); holes read zeros so a first
         write to a sparse stripe deltas against P=0 and lands the exact
         encode; the engine aborts failed commits atomically, so the
-        bounded `_ec_retry` re-reads an unchanged base. Every job runs
-        synchronously — a failed cell is dirty-marked exactly like the
+        bounded `_ec_retry` re-reads an unchanged base. The touched cells'
+        writes run beside the parity call (`_ec_fanout`) and every job is
+        waited for — a failed cell is dirty-marked exactly like the
         full path (parity was applied for the INTENDED new data, so
         rebuild decodes the marked cell to that content)."""
         k, p, cs = self._ec
@@ -2458,43 +2541,20 @@ class _ClusterRouter(_SubmitReap):
                         old ^ new_media[lo - bo:hi - bo]
         except StorageError as e:
             raise _EcDeltaUnavailable(str(e)) from e
-        with tracing.span("ros2.ec.parity.submit", op=op,
-                          bytes=deltas.size):
-            pending = rs.ec_parity_delta(k, p, list(touched), deltas)
-        with tracing.span("ros2.ec.parity.wait", op=op):
-            pdeltas = np.asarray(pending)
-        jobs: List[Tuple[int, Callable[[_ServerIO], None]]] = []
+        free = []
         for i in touched:
             lo, hi = max(bo, i * cs), min(bo + ln, (i + 1) * cs)
             sub = frag[lo - bo:hi - bo]
-            jobs.append((i, lambda s, fo=b * BLOCK + lo, sub=sub:
+            free.append((i, lambda s, fo=b * BLOCK + lo, sub=sub:
                          s.writev(oid, fo, [sub])))
-        for j in range(p):
-            jobs.append((k + j, lambda s, co=(k + j) * cs + w0,
-                         pay=pdeltas[j]: s.xor_apply(oid, b, co, pay)))
-
-        failed: List[int] = []
-        flock = threading.Lock()
-
-        def run(cell: int, fn) -> None:
-            sess = self.sessions[order[cell]]
-            with tracing.span("ros2.ec.cell", op=op, cell=cell,
-                              tid=sess.label):
-                try:
-                    self._ec_retry(lambda: fn(sess))
-                except StorageError:
-                    with flock:
-                        failed.append(cell)
-                    self._ec_mark_dirty(oid, b, [cell], op=op)
-                    note_recovery(self._faults, "ec.cell_write_degraded")
-
-        with tracing.span("ros2.ec.fanout", op=op, cells=len(jobs)):
-            if len(jobs) == 1:
-                run(*jobs[0])
-            else:
-                pool = self._get_pool()
-                for f in [pool.submit(run, cell, fn) for cell, fn in jobs]:
-                    f.result()
+        needs = [(k + j, lambda s, par, co=(k + j) * cs + w0, j=j:
+                  s.xor_apply(oid, b, co, par[j])) for j in range(p)]
+        with self._map_lock:
+            up = dict(self._up)
+        failed = self._ec_fanout(
+            oid, b, order, up, free, needs,
+            lambda: rs.ec_parity_delta(k, p, list(touched), deltas),
+            deltas.size, quorum=None, op=op)
         with self._map_lock:
             self.ec_delta_writes += 1
             self.ec_delta_bytes_saved += k * cs - fetched
@@ -2755,6 +2815,8 @@ class _ClusterRouter(_SubmitReap):
                     # process-wide: the cache is shared by every caller
                     "parity_coeff_hits": rs.coeff_cache.hits,
                     "parity_coeff_misses": rs.coeff_cache.misses,
+                    "parity_overlap_writes": self.ec_parity_overlap_writes,
+                    "parity_serial_writes": self.ec_parity_serial_writes,
                 }
         return counters_registry.verify(out)
 

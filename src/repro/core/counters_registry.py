@@ -103,6 +103,7 @@ EC_KEYS = frozenset({
     "k", "p", "degraded_reads", "reconstructions", "rebuilt_cells",
     "delta_writes", "delta_bytes_saved", "delta_fallbacks",
     "parity_coeff_hits", "parity_coeff_misses",
+    "parity_overlap_writes", "parity_serial_writes",
 })
 
 FAULTS_KEYS = frozenset({

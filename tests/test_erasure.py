@@ -3,6 +3,9 @@ Reed-Solomon striping of k data + p parity cells across distinct targets,
 k+1 ack quorum with background stragglers, degraded reads reconstructing
 from any k clean survivors, dirty-cell ledgers, and marker-driven rebuild
 that regenerates ONLY the lost cells through the heal throttle."""
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -387,6 +390,178 @@ def test_parity_coefficient_cache_counts_hits_in_router_counters():
         - before["ec"]["parity_coeff_hits"] == n
     assert after["ec"]["parity_coeff_misses"] \
         == before["ec"]["parity_coeff_misses"]
+    # every EC write so far (one full, n + 1 delta) had a data cell in
+    # flight before its parity wait: all targets are up
+    assert after["ec"]["parity_overlap_writes"] == n + 2
+    assert after["ec"]["parity_serial_writes"] == 0
+    c.close()
+
+
+def _hold_parity(monkeypatch, c, fail=None):
+    """Stand in for both parity calls: the pending result's `__array__`
+    waits (5 s at most) until a data cell's `writev` has started, then
+    returns the real parity, or raises `fail`. Returns the event log."""
+    from repro.kernels.rs_parity import ops as rs
+    started = threading.Event()
+    log = []
+
+    class Held:
+        def __init__(self, out):
+            self.out = out
+
+        def __array__(self, dtype=None, copy=None):
+            assert started.wait(5.0), "the parity wait held back every cell"
+            log.append("parity")
+            if fail is not None:
+                raise fail
+            return np.asarray(self.out, dtype)
+
+    for name in ("ec_encode", "ec_parity_delta"):
+        monkeypatch.setattr(rs, name, lambda *a, real=getattr(rs, name),
+                            **kw: Held(real(*a, **kw)))
+    for s in c.io.sessions.values():
+        def writev(oid, offset, buffers, real=s.writev):
+            log.append("writev")
+            started.set()
+            return real(oid, offset, buffers)
+        monkeypatch.setattr(s, "writev", writev)
+    return started, log
+
+
+def _stripe_cells(c, b, oid=None):
+    """The k + p stored cells of stripe `b`, raw, after stragglers."""
+    k, p, cs = c.io._ec
+    c.io._ec_drain()
+    oid = _oid(c) if oid is None else oid
+    order = c.io._ec_order(oid, b)
+    return np.stack([c.io.sessions[order[i]].fetch_cell(oid, b, i * cs, cs)
+                     for i in range(k + p)])
+
+
+def _assert_stripe_is_encode(c, b, block, oid=None):
+    from repro.kernels.rs_parity import ref
+    k, p, cs = c.io._ec
+    data = np.frombuffer(block, np.uint8).reshape(k, cs)
+    want = np.concatenate([data, ref.rs_encode_np(data, p)])
+    np.testing.assert_array_equal(_stripe_cells(c, b, oid), want)
+
+
+def test_ec_data_cells_move_before_the_parity_wait(monkeypatch):
+    """A delta write and a full-stripe write each start a data cell's
+    `writev` before their parity result is released (the stand-in's
+    `__array__` blocks until one has started, so the parity-first order
+    times out). The stored stripe is the reference encode, and both
+    writes count as overlapped."""
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    fd = c.open("/f", create=True)
+    shadow = bytearray(_payload(BLOCK, 95))
+    c.pwrite(fd, bytes(shadow), 0)
+    before = c.io.data_path_counters()["ec"]
+    started, log = _hold_parity(monkeypatch, c)
+    for off, ln, seed in ((8192, 4096, 96), (0, BLOCK, 97)):
+        started.clear()
+        log.clear()
+        data = _payload(ln, seed)
+        c.pwrite(fd, data, off)
+        shadow[off:off + ln] = data
+        assert log.index("writev") < log.index("parity")
+    monkeypatch.undo()
+    after = c.io.data_path_counters()["ec"]
+    assert after["delta_writes"] - before["delta_writes"] == 1
+    assert after["parity_overlap_writes"] \
+        - before["parity_overlap_writes"] == 2
+    assert after["parity_serial_writes"] == before["parity_serial_writes"]
+    _assert_stripe_is_encode(c, 0, bytes(shadow))
+    assert c.pread(fd, BLOCK, 0) == bytes(shadow)
+    c.close()
+
+
+def test_ec_concurrent_writers_share_each_parity_under_fast_switching():
+    """Ten writers, more than the router pool's eight workers, each make
+    full-stripe and delta writes to a file of their own at once, with
+    the interpreter switching threads every microsecond. A write's
+    parity jobs share one result that only the device computes, so no
+    job waits on another: every writer ends within its join timeout,
+    every stripe is the reference encode, and the overlap counters add
+    up to the writes made."""
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    n, blocks, small = 10, 2, 4
+    fds = [c.open(f"/w{i}", create=True) for i in range(n)]
+    shadows = [bytearray(blocks * BLOCK) for _ in range(n)]
+    before = c.io.data_path_counters()["ec"]
+    errs = []
+
+    def writer(i):
+        rng = np.random.default_rng(200 + i)
+        try:
+            for b in range(blocks):
+                data = _payload(BLOCK, 300 + 10 * i + b)
+                c.pwrite(fds[i], data, b * BLOCK)
+                shadows[i][b * BLOCK:(b + 1) * BLOCK] = data
+            for j in range(small):
+                off = int(rng.integers(0, blocks * BLOCK // 4096)) * 4096
+                data = _payload(4096, 400 + 10 * i + j)
+                c.pwrite(fds[i], data, off)
+                shadows[i][off:off + 4096] = data
+        except Exception as e:   # noqa: BLE001 - reported below
+            errs.append(e)
+
+    threads = [threading.Thread(target=writer, args=(i,)) for i in range(n)]
+    old_interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old_interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errs == []
+    after = c.io.data_path_counters()["ec"]
+    assert after["delta_writes"] - before["delta_writes"] == n * small
+    assert after["parity_overlap_writes"] \
+        - before["parity_overlap_writes"] == n * (blocks + small)
+    assert after["parity_serial_writes"] == before["parity_serial_writes"]
+    for i, fd in enumerate(fds):
+        oid = c.dfs._open[fd].oid
+        for b in range(blocks):
+            _assert_stripe_is_encode(
+                c, b, bytes(shadows[i][b * BLOCK:(b + 1) * BLOCK]), oid)
+        assert c.pread(fd, blocks * BLOCK, 0) == bytes(shadows[i])
+    c.close()
+
+
+@pytest.mark.parametrize("path", ["delta", "full"])
+def test_ec_parity_failure_ledgers_parity_and_heals(monkeypatch, path):
+    """A parity call that raises (not a StorageError) after the data
+    cells moved fails the write with StorageError and leaves exactly the
+    p parity cells in the stripe's ledger. The next full write heals the
+    stripe: ledger empty, every cell the reference encode, and the bytes
+    read back with two targets down."""
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    k, p, cs = c.io._ec
+    fd = c.open("/f", create=True)
+    c.pwrite(fd, _payload(BLOCK, 100), 0)
+    off, ln = (8192, 4096) if path == "delta" else (0, BLOCK)
+    _hold_parity(monkeypatch, c, fail=RuntimeError("device lost"))
+    with pytest.raises(StorageError) as err:
+        c.pwrite(fd, _payload(ln, 101), off)
+    assert isinstance(err.value.__cause__, RuntimeError)
+    monkeypatch.undo()
+    c.io._ec_drain()
+    assert list(_dirty_union(c, k + p).values()) == [set(range(k, k + p))]
+    block = _payload(BLOCK, 102)
+    c.pwrite(fd, block, 0)
+    assert _dirty_union(c, k + p) == {}
+    _assert_stripe_is_encode(c, 0, block)
+    order = c.io._ec_order(_oid(c), 0)
+    c.cluster.fail_target(order[0])
+    c.cluster.fail_target(order[1])
+    assert c.pread(fd, BLOCK, 0) == block
     c.close()
 
 
