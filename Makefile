@@ -1,7 +1,7 @@
 # Dev workflow targets (see ROADMAP.md "Dev workflow").
 PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test test-witnessed lint bench bench-smoke check
+.PHONY: test test-witnessed lint check
 
 test:                 ## tier-1 verify
 	PYTHONPATH=$(PYTHONPATH) python -m pytest -x -q
@@ -12,15 +12,7 @@ test-witnessed:       ## tier-1 + lock-order witness (latent deadlocks)
 lint:                 ## repo-invariant linter (tools/analysis), <2s
 	python -m tools.analysis.lint
 
-bench:                ## full data-path benchmark -> BENCH_data_path.json
-	PYTHONPATH=$(PYTHONPATH) python benchmarks/bench_data_path.py
-
-bench-smoke:          ## ~45s gate: fails if zero_copy regresses below sg
-	bash benchmarks/smoke.sh
-
-# check = lint + witnessed tier-1 tests + the smoke gate (8-target
-# four-domain pool map: data-path, control-path, cluster-routing,
-# scaling, fault, EC and delta-RMW regressions all fail fast; the
-# lock-order and leak witnesses ride the test run) — run it before
-# landing anything that touches the stack.
-check: lint test-witnessed bench-smoke  ## lint + tests + smoke gate
+# check = lint + witnessed tier-1 tests (the lock-order and leak
+# witnesses ride the test run) — run it before landing anything that
+# touches the stack. The chip benchmark is bench/run.py.
+check: lint test-witnessed  ## lint + witnessed tests

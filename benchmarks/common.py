@@ -2,40 +2,15 @@
 from __future__ import annotations
 
 import json
-import os
 import time
 from pathlib import Path
 from typing import Dict, List, Sequence
-
-# THE fleet-aware counter merge (sums numeric leaves across nested counter
-# dicts) — one implementation, shared by the cluster router's fleet-wide
-# `data_path_counters()` and every benchmark that combines counters.
-from repro.core.client import merge_counters  # noqa: F401  (re-export)
 
 RESULTS = Path(__file__).resolve().parent.parent / "results" / "bench"
 
 GiB = 1024 ** 3
 MiB = 1024 ** 2
 KiB = 1024
-
-
-def flatten_counters(d: Dict, prefix: str = "") -> Dict:
-    """Nested counter dict -> flat {"a.b.c": v} (the benchmarks' common
-    view for deltas and JSON reporting)."""
-    out = {}
-    for k, v in d.items():
-        if isinstance(v, dict):
-            out.update(flatten_counters(v, f"{prefix}{k}."))
-        else:
-            out[f"{prefix}{k}"] = v
-    return out
-
-
-def delta_counters(before: Dict, after: Dict) -> Dict:
-    """Per-key numeric delta of two flat counter snapshots."""
-    return {k: after[k] - before.get(k, 0) for k in after
-            if isinstance(after[k], (int, float))
-            and not isinstance(after[k], bool)}
 
 
 def save_json(name: str, payload) -> Path:
@@ -75,8 +50,8 @@ def device_direct_compare(client, n_tensors: int, tensor_bytes: int,
                           slot_bytes: int, n_slots: int = 4,
                           trials: int = 1, path: str = "/dd-tensors",
                           seed: int = 0) -> Dict[str, float]:
-    """Shared single-vs-batched device-direct harness (bench_data_path's
-    smoke gate and train_ingest's rdma leg both run THIS protocol): write
+    """Single-vs-batched device-direct harness (train_ingest's rdma leg):
+    write
     `n_tensors` float32 tensors to one DFS file, warm the sink (jit
     compiles + caches), then time `read_tensor` per tensor against one
     `read_tensors` batch, min over `trials`."""

@@ -16,10 +16,12 @@ demonstrated against the functional system.
 """
 import numpy as np
 
+from repro.core import transport_model as tm
 from repro.core.client import ROS2Client
 from repro.core.data_plane import AccessError, RDMATransport
 from repro.core.device_direct import DeviceDirectSink
-from repro.core.sim import GiB, KiB, MiB
+from repro.core.media import make_nvme_array, striped_stations
+from repro.core.sim import GiB, KiB, MiB, mva
 from repro.distributed.fault import FailureInjector
 
 
@@ -27,16 +29,29 @@ def section(title):
     print(f"\n=== {title} ===")
 
 
+def modeled_ops_per_s(mode, transport, io_size, write=False, jobs=16,
+                      iodepth=8, n_devices=4):
+    """Calibrated MVA model of one client against one 4-SSD engine: the
+    client, network, server and media service-demand pipeline."""
+    plat = tm.DPU if mode == "dpu" else tm.HOST
+    stations = (tm.client_stations(plat, transport, io_size, write,
+                                   plat.n_cores)
+                + tm.network_stations(io_size)
+                + tm.server_stations(transport, io_size, write)
+                + striped_stations(make_nvme_array(n_devices), io_size,
+                                   write))
+    x, _ = mva(stations, jobs * iodepth)
+    return x
+
+
 def main():
     section("1. modeled end-to-end performance (paper Fig. 5)")
     for mode in ("host", "dpu"):
         for transport in ("tcp", "rdma"):
-            c = ROS2Client(mode=mode, transport=transport, n_devices=4)
-            bw = c.model_throughput(MiB, write=False, jobs=16) / GiB
-            io = c.model_iops(4 * KiB, write=False, jobs=16) / 1e3
+            bw = modeled_ops_per_s(mode, transport, MiB) * MiB / GiB
+            io = modeled_ops_per_s(mode, transport, 4 * KiB) / 1e3
             print(f"  {mode:4s}/{transport:4s}: 1MiB read {bw:5.1f} GiB/s   "
                   f"4KiB read {io:6.0f} kIOPS")
-            c.close()
     print("  -> DPU+RDMA == host; DPU+TCP collapses (RX path)")
 
     section("2. transport semantics (counted, not claimed)")

@@ -10,7 +10,7 @@ mode="dpu":  the DFS client runs on the SmartNIC worker pool; the host only
              rings doorbells (ROS2Client.submit/poll or the sync wrappers).
 transport:   "rdma" (zero-copy, rkey-checked) or "tcp" (two-copy, segmented).
 
-Data-path anatomy (the zero-copy path, default):
+Data-path anatomy (one path for every client):
 
     pread:  DIRECT SPLICE (RDMA): the engine scatters the verified extent
             overlay STRAIGHT into the caller's registered region through
@@ -19,7 +19,7 @@ Data-path anatomy (the zero-copy path, default):
             byte end-to-end, ZERO staging-ring acquires; warm re-reads
             skip the Fletcher-64 via the verified-extent cache. TCP and
             unregistered callers keep the staged path (fetch_into a ring
-            slot, then the SG splice — the bounce is now counted in
+            slot, then the SG splice — the bounce is counted in
             `staging.bounce_bytes`).
     pwrite: each iovec buffer registered once per writev (zero-copy wrap,
             no MR churn per block) --ONE write_sg per batch--> staging
@@ -39,31 +39,21 @@ Data-path anatomy (the zero-copy path, default):
             per-buffer destinations — no contiguous intermediate bytes,
             no staging bounce.
 
-Control path (PR 3): session bring-up is ONE compound RPC (connect +
-mount + grant_rkey), warm opens are served from the leased MetadataCache
-(0 round-trips), and the staging rkey's lease is renewed before expiry —
-host thread or DPU housekeeping — so long runs never hard-fault on a
-lapsed capability. `legacy=True` keeps the seed's per-step control
-traffic as the measured baseline.
+Control path: session bring-up is ONE compound RPC (connect + mount +
+grant_rkey per target, plus the pool map on a routed client), warm opens
+are served from the leased MetadataCache (0 round-trips), and the staging
+rkey's lease is renewed before expiry — host thread or DPU housekeeping —
+so long runs never hard-fault on a lapsed capability.
 
 Inline crypto (when enabled) is applied on the staging leg — the DPU-
 adjacent bounce buffer — with per-block nonces and block-absolute
-keystream offsets (partial-block reads decrypt at the stream position the
-write used), identically on the zero-copy and legacy paths so both
-interoperate on the same stored bytes. The keystream PRF is bit-identical
-to the stream_cipher Pallas kernel, and warm keystream pages come from an
-LRU (no PRF regeneration).
+keystream offsets, so a read whose range differs from the write's block
+split decrypts at the stream position the write used. The keystream PRF
+is bit-identical to the stream_cipher Pallas kernel, and warm keystream
+pages come from an LRU (no PRF regeneration).
 
-`zero_copy=False` reproduces the PR-1 scatter-gather path (tobytes per
-block, verify every read, no donation, per-descriptor TCP requests);
-`legacy=True` keeps the seed per-block path (one transport op + one MR
-register/deregister per block, global engine lock, scalar CRC32 extent
-checksums). Benchmarks measure all three in the same run, with
-`_ServerIO.data_path_counters()` providing first-class copy/checksum/
-keystream accounting.
-
-Perf numbers for any workload come from `stations()` + core.sim.mva — the
-same calibrated model the paper-figure benchmarks use.
+`_ServerIO.data_path_counters()` provides first-class copy/checksum/
+keystream accounting; the chip benchmark under `bench/` reads it.
 """
 from __future__ import annotations
 
@@ -79,7 +69,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import transport_model as tm
 from repro.core import counters_registry
 from repro.core import tracing
 from repro.core.control_plane import ControlPlane
@@ -92,13 +81,10 @@ from repro.core.faults import (DEFAULT_TIMEOUTS, FaultInjector,
                                InjectedTransientError, OpTimeout, Timeouts,
                                note_recovery)
 from repro.core.metadata_cache import MetadataCache
-from repro.core.media import (Device, crc32_checksum, make_nvme_array,
-                              striped_stations)
 from repro.core.object_store import (EC_DIRTY_AKEY, MediaScrubber,
                                      ObjectStore, StorageCluster,
                                      StorageError, TargetDownError,
                                      placement_order)
-from repro.core.sim import Station, mva
 from repro.core.smartnic import DPURuntime, InlineCrypto
 
 
@@ -107,8 +93,7 @@ def merge_counters(dicts: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     (possibly nested) counter dicts, recursing into sub-dicts; the first
     occurrence wins for non-numeric values. This is THE counter-merge used
     everywhere counters from more than one source meet — the cluster
-    router merging per-target sessions, and the benchmarks merging run
-    deltas (benchmarks/common.py re-exports it)."""
+    router merging its per-target sessions."""
     out: Dict[str, Any] = {}
     for d in dicts:
         for k, v in d.items():
@@ -688,7 +673,6 @@ class _ServerIO(_SubmitReap):
     `split_blocks` output into one scatter-gather transport op per staging
     batch, stage through the per-slot-locked ring (no global lock), and
     commit/fetch through the engine's batched `update_many`/`fetch_into`.
-    `legacy=True` preserves the seed per-block path for comparison.
 
     `target_up` (cluster sessions) is the server-side admission check: an
     op routed here by a STALE client map while the pool map says this
@@ -707,13 +691,12 @@ class _ServerIO(_SubmitReap):
                  server_registry: MemoryRegistry, transport: str,
                  tenant: str, control: ControlPlane,
                  crypto: Optional[InlineCrypto] = None,
-                 n_staging_slots: int = 16, legacy: bool = False,
-                 zero_copy: bool = True,
+                 n_staging_slots: int = 16,
                  target_up: Optional[Callable[[], bool]] = None,
                  faults: Optional[FaultInjector] = None,
                  timeouts: Timeouts = DEFAULT_TIMEOUTS,
                  label: Optional[str] = None,
-                 io_depth: int = 16, tcp_registered: bool = False):
+                 io_depth: int = 16):
         self.container = engine_container
         self._target_up = target_up
         self._faults = faults
@@ -725,12 +708,10 @@ class _ServerIO(_SubmitReap):
         self.cp = control
         self.crypto = crypto
         self.transport_kind = transport
-        self.legacy = legacy
-        self.zero_copy = zero_copy and not legacy
         # direct read splice: server-initiated placement straight into the
         # caller's registered destination (RDMA only — TCP has no way to
         # land bytes in caller memory without the kernel staging them)
-        self.direct_reads = self.zero_copy and transport == "rdma"
+        self.direct_reads = transport == "rdma"
         self.host_copy_bytes = 0      # client-side materialization copies
         self.bounce_bytes = 0         # engine->ring staging on STAGED reads
         # destination-capability cache: one granted rkey per registered
@@ -742,20 +723,15 @@ class _ServerIO(_SubmitReap):
             = OrderedDict()
         self._dst_rkey_ttl = 3600.0
         self._dst_rkey_lock = threading.Lock()
-        # server staging ring (bounce buffers) for the engine side; the
-        # legacy path uses the same region through `self.staging`
+        # server staging ring (bounce buffers) for the engine side
         self.ring = _StagingRing(self.sreg, n_staging_slots, BLOCK, tenant,
                                  timeouts=timeouts, label=label)
         self.staging = self.ring.region
-        if self.zero_copy:
-            self.ring.set_reclaim(self._reclaim_donations)
-        self.tcp_registered = tcp_registered and transport != "rdma"
+        self.ring.set_reclaim(self._reclaim_donations)
         if transport == "rdma":
             self.xport = RDMATransport(local=self.creg, remote=self.sreg)
         else:
-            self.xport = TCPTransport(local=self.creg, remote=self.sreg,
-                                      sendmsg_batching=self.zero_copy,
-                                      registered=self.tcp_registered)
+            self.xport = TCPTransport(local=self.creg, remote=self.sreg)
         self.xport.faults = faults
         # submit/reap state: shared CQ + this target's submission ring
         self._init_submit(io_depth, timeouts)
@@ -766,7 +742,6 @@ class _ServerIO(_SubmitReap):
         self._sid: Optional[int] = None
         self.staging_rkey: Optional[str] = None
         self.cache = None               # MetadataCache (rkey lease watch)
-        self._lock = threading.Lock()           # legacy path only
         # concurrency gauge: how many reads are in flight right now / ever
         self._gauge_lock = threading.Lock()
         self._active_reads = 0
@@ -926,10 +901,7 @@ class _ServerIO(_SubmitReap):
 
     # -- vectored write path -------------------------------------------------
     def write(self, oid: int, offset: int, data) -> None:
-        if self.legacy:
-            self._write_legacy(oid, offset, data)
-        else:
-            self.writev(oid, offset, [data])
+        self.writev(oid, offset, [data])
 
     def writev(self, oid: int, offset: int, buffers: Sequence) -> int:
         """Blocking vectored write — submit + wait with inline execution
@@ -945,20 +917,12 @@ class _ServerIO(_SubmitReap):
         (one transport op each, descriptors pointing into the caller's own
         regions), and committed via `update_many` (one epoch per writev).
 
-        On the zero-copy path the staged block is encrypted IN PLACE
-        (fused `apply_into`, no temporary) and its ring slot DONATED to
-        media: every replica commits the buffer by reference under a
-        SlotLease, so the op-critical path performs zero post-splice
-        copies; media's deferred writeback (pressure/read-triggered) pays
-        the NAND program later. With `zero_copy=False` the PR-1 behavior
-        (one `tobytes` materialization per block) is preserved."""
-        if self.legacy:
-            pos = offset
-            for a in buffers:
-                b = bytes(a)
-                self._write_legacy(oid, pos, b)
-                pos += len(b)
-            return pos - offset
+        The staged block is encrypted IN PLACE (fused `apply_into`, no
+        temporary) and its ring slot DONATED to media: every replica
+        commits the buffer by reference under a SlotLease, so the
+        op-critical path performs zero post-splice copies; media's
+        deferred writeback (pressure/read-triggered) pays the NAND program
+        later."""
         self._admit()
         arrs = [a if isinstance(a, np.ndarray)
                 else np.frombuffer(bytes(a), np.uint8) for a in buffers]
@@ -1007,23 +971,12 @@ class _ServerIO(_SubmitReap):
                     items, leases = [], []
                     for (b, bo, ln), s in zip(batch, slots):
                         view = self.ring.view(s)[:ln]
-                        if self.crypto is not None:
-                            if self.zero_copy:      # fused in-place XOR
-                                self.crypto.apply_into(
-                                    view, view, nonce=oid * (1 << 20) + b,
-                                    offset=bo)
-                            else:
-                                view[:] = self.crypto.apply(
-                                    view, nonce=oid * (1 << 20) + b,
-                                    offset=bo)
-                        if self.zero_copy:
-                            items.append((str(b), AKEY, bo, view))
-                            leases.append(self.ring.donate(s))
-                        else:
-                            items.append((str(b), AKEY, bo, view.tobytes()))
-                            leases.append(None)
-                            with self._gauge_lock:   # concurrent DPU writers
-                                self.host_copy_bytes += ln
+                        if self.crypto is not None:     # fused in-place XOR
+                            self.crypto.apply_into(
+                                view, view, nonce=oid * (1 << 20) + b,
+                                offset=bo)
+                        items.append((str(b), AKEY, bo, view))
+                        leases.append(self.ring.donate(s))
                     obj.update_many(items, epoch=epoch, leases=leases)
                     pos = p
                 finally:
@@ -1040,23 +993,13 @@ class _ServerIO(_SubmitReap):
         staging-ring concurrency). This bounce is a real host copy the
         direct-splice path eliminates — counted in `bounce_bytes` so
         copies/byte stays honest on the staged path. Decrypt is the fused
-        single-pass `apply_into` on the zero-copy path (PR-1's
-        generate+XOR+copy-back is kept behind `zero_copy=False`)."""
+        single-pass `apply_into`."""
         obj.fetch_into(str(b), AKEY, bo, ln, view)
         with self._gauge_lock:
             self.bounce_bytes += ln
         if self.crypto is not None:
-            if self.zero_copy:
-                self.crypto.apply_into(view[:ln], view[:ln],
-                                       nonce=oid * (1 << 20) + b, offset=bo)
-            else:
-                view[:ln] = self.crypto.apply(view[:ln],
-                                              nonce=oid * (1 << 20) + b,
-                                              offset=bo)
-
-    @property
-    def supports_readv_into(self) -> bool:
-        return self.zero_copy
+            self.crypto.apply_into(view[:ln], view[:ln],
+                                   nonce=oid * (1 << 20) + b, offset=bo)
 
     def readv_into(self, oid: int, offset: int, bufs: Sequence) -> int:
         """Blocking vectored gather-read — submit + wait with inline
@@ -1095,8 +1038,6 @@ class _ServerIO(_SubmitReap):
         (per-slot locks, no engine-wide lock) and land with one SG splice
         per batch. This is the GPUDirect-RDMA analogue's transport leg
         (core.device_direct builds on it)."""
-        if self.legacy:
-            return self._read_into_legacy(oid, offset, size, dst_mr, dst_off)
         return self._gather_into(oid, offset, [(dst_mr, dst_off, size)])
 
     def _dst_rkey(self, mr: MemoryRegion) -> str:
@@ -1307,8 +1248,6 @@ class _ServerIO(_SubmitReap):
         return self.submit_read(oid, offset, size, _inline=True).wait()
 
     def _read_impl(self, oid: int, offset: int, size: int) -> bytes:
-        if self.legacy:
-            return self._read_legacy(oid, offset, size)
         dst = self.creg.register(np.empty(size, np.uint8), self.tenant)
         try:
             self._read_into_impl(oid, offset, size, dst, 0)
@@ -1415,16 +1354,8 @@ class _ServerIO(_SubmitReap):
                     self._xport_op(
                         lambda: self.xport.write_sg(self.staging, iov))
                 view = self.ring.view(s)[:ln]
-                if self.zero_copy:
-                    obj.update_many([(str(block), AKEY, cell_off, view)],
-                                    epoch=epoch,
-                                    leases=[self.ring.donate(s)])
-                else:
-                    obj.update_many(
-                        [(str(block), AKEY, cell_off, view.tobytes())],
-                        epoch=epoch, leases=[None])
-                    with self._gauge_lock:
-                        self.host_copy_bytes += ln
+                obj.update_many([(str(block), AKEY, cell_off, view)],
+                                epoch=epoch, leases=[self.ring.donate(s)])
             finally:
                 self.ring.release(slots)
         finally:
@@ -1542,79 +1473,6 @@ class _ServerIO(_SubmitReap):
         if not any(obj.fetch(dk, EC_DIRTY_AKEY, 0, n_cells)):
             obj.punch(dk, EC_DIRTY_AKEY)
 
-    # -- seed per-block path (kept verbatim for `legacy=True` benchmarks) ----
-    def _write_legacy(self, oid: int, offset: int, data) -> None:
-        arr = np.frombuffer(bytes(data), np.uint8) if not isinstance(
-            data, np.ndarray) else data
-        obj = self.container.object(oid)
-        with self._lock:
-            pos = 0
-            for b, bo, ln in split_blocks(offset, arr.size):
-                chunk = arr[pos:pos + ln]
-                if self.crypto is not None:
-                    chunk = self.crypto.apply(chunk, nonce=oid * (1 << 20) + b,
-                                              offset=bo)
-                src = self.creg.register(np.ascontiguousarray(chunk),
-                                         self.tenant)
-                try:
-                    if self.transport_kind == "rdma":
-                        self.xport.write(self._staging_token(), self.tenant, 0,
-                                         src, 0, ln)
-                    else:
-                        self.xport.write(self.staging, 0, src, 0, ln)
-                    obj.update(str(b), AKEY, bo,
-                               self.staging.buf[:ln].tobytes())
-                finally:
-                    self.creg.deregister(src)
-                pos += ln
-
-    def _read_into_legacy(self, oid: int, offset: int, size: int,
-                          dst_mr: MemoryRegion, dst_off: int = 0) -> int:
-        obj = self.container.object(oid)
-        with self._lock:
-            pos = 0
-            for b, bo, ln in split_blocks(offset, size):
-                data = obj.fetch(str(b), AKEY, bo, ln)
-                self.staging.buf[:ln] = np.frombuffer(data, np.uint8)
-                if self.crypto is not None:
-                    self.staging.buf[:ln] = self.crypto.apply(
-                        self.staging.buf[:ln], nonce=oid * (1 << 20) + b,
-                        offset=bo)
-                if self.transport_kind == "rdma":
-                    self.xport.read(self._staging_token(), self.tenant, 0,
-                                    dst_mr, dst_off + pos, ln)
-                else:
-                    self.xport.read(self.staging, 0, dst_mr,
-                                    dst_off + pos, ln)
-                pos += ln
-        return size
-
-    def _read_legacy(self, oid: int, offset: int, size: int) -> bytes:
-        obj = self.container.object(oid)
-        out = np.zeros(size, np.uint8)
-        with self._lock:
-            pos = 0
-            for b, bo, ln in split_blocks(offset, size):
-                data = obj.fetch(str(b), AKEY, bo, ln)
-                self.staging.buf[:ln] = np.frombuffer(data, np.uint8)
-                dst = self.creg.register(ln, self.tenant)
-                try:
-                    if self.transport_kind == "rdma":
-                        self.xport.read(self._staging_token(), self.tenant, 0,
-                                        dst, 0, ln)
-                    else:
-                        self.xport.read(self.staging, 0, dst, 0, ln)
-                    chunk = dst.buf[:ln]
-                    if self.crypto is not None:
-                        chunk = self.crypto.apply(chunk,
-                                                  nonce=oid * (1 << 20) + b,
-                                                  offset=bo)
-                    out[pos:pos + ln] = chunk
-                finally:
-                    self.creg.deregister(dst)
-                pos += ln
-        return out.tobytes()
-
 
 class _EcDeltaUnavailable(Exception):
     """The delta-parity RMW path lost a prerequisite BEFORE dispatch (an
@@ -1658,7 +1516,6 @@ class _ClusterRouter(_SubmitReap):
                  client_registry: MemoryRegistry, tenant: str,
                  make_session: Callable[[int], _ServerIO],
                  cluster_stats: Callable[[], Any],
-                 zero_copy: bool = True,
                  faults: Optional[FaultInjector] = None,
                  timeouts: Timeouts = DEFAULT_TIMEOUTS,
                  redundancy_key: Optional[str] = None,
@@ -1670,7 +1527,6 @@ class _ClusterRouter(_SubmitReap):
         self.tenant = tenant
         self._make_session = make_session
         self._cluster_stats = cluster_stats
-        self.zero_copy = zero_copy
         self._faults = faults
         self.timeouts = timeouts
         # erasure-coded redundancy class, learned from the pool map (the
@@ -2000,10 +1856,6 @@ class _ClusterRouter(_SubmitReap):
         return total
 
     # -- vectored read path --------------------------------------------------
-    @property
-    def supports_readv_into(self) -> bool:
-        return self.zero_copy
-
     def _gather_into(self, oid: int, offset: int, dsts: Sequence) -> int:
         self._ensure_map()
         if self._ec is not None:
@@ -2877,8 +2729,7 @@ class ROS2Client:
                  secret: str = "secret", inline_encryption: bool = False,
                  replication: int = 2, write_quorum: Optional[int] = None,
                  n_dpu_cores: int = 16,
-                 n_staging_slots: int = 16, legacy: bool = False,
-                 zero_copy: bool = True,
+                 n_staging_slots: int = 16,
                  scrub_interval_s: Optional[float] = 1.0,
                  rkey_ttl_s: float = 3600.0,
                  meta_lease_s: float = 30.0,
@@ -2890,27 +2741,19 @@ class ROS2Client:
                  timeouts: Optional[Timeouts] = None,
                  ec: Optional[Tuple[int, int]] = None,
                  domains: Optional[Sequence[Optional[str]]] = None,
-                 io_depth: int = 16, tcp_registered: bool = False):
+                 io_depth: int = 16):
         assert mode in ("host", "dpu") and transport in ("tcp", "rdma")
         assert n_targets >= 1
-        assert n_targets == 1 or not legacy, \
-            "the seed legacy path is single-target only"
-        assert ec is None or (n_targets >= 2 and not legacy), \
+        assert ec is None or n_targets >= 2, \
             "ec(k,p) requires a routed multi-target cluster"
         assert domains is None or len(domains) == n_targets
         self.mode, self.transport = mode, transport
-        zero_copy = zero_copy and not legacy
-        self.zero_copy = zero_copy
-        self.legacy = legacy
         self.tenant = tenant
         self._n_staging_slots = n_staging_slots
         self._rkey_ttl_s = rkey_ttl_s
-        # submit/reap knobs: io_depth bounds in-flight ops per target (SQ
-        # ring depth) and sizes the dpu-mode doorbell batch;
-        # tcp_registered turns on the io_uring-style registered-buffer
-        # receive leg (TCP only — RDMA reads are already zero-staging)
+        # submit/reap knob: io_depth bounds in-flight ops per target (SQ
+        # ring depth) and sizes the dpu-mode doorbell batch
         self.io_depth = max(1, int(io_depth))
-        self.tcp_registered = tcp_registered
         self._submit_batch: List["_DPUSubmitHandle"] = []
         self._submit_batch_lock = threading.Lock()
         # one injectable policy for every data-path wait (staging ring,
@@ -2925,7 +2768,6 @@ class ROS2Client:
         # _ClusterRouter with one session per target)
         self.cluster = StorageCluster(
             n_targets=n_targets, n_devices=n_devices,
-            csum=crc32_checksum if legacy else None,
             timeouts=self.timeouts, domains=domains)
         if fault_injector is not None:
             self.cluster.set_faults(fault_injector)
@@ -2937,13 +2779,13 @@ class ROS2Client:
         self.store = self.cluster.targets[0].store
         self.devices = self.store.devices
         pool = self.cluster.create_pool("pool0")
-        # DFS reads never pin historical epochs, so the vectored client runs
-        # with epoch aggregation on; legacy keeps seed full-history extents.
-        # zero_copy=False also pins the PR-1 verify-every-read engine.
+        # DFS reads never pin historical epochs, so the client runs with
+        # epoch aggregation on, and warm re-reads skip the checksum through
+        # the verified-extent cache (the scrubber bounds its window)
         self.ccontainer = pool.create_container("cont0",
                                                 replication=replication,
-                                                aggregate=not legacy,
-                                                verified_cache=zero_copy,
+                                                aggregate=True,
+                                                verified_cache=True,
                                                 write_quorum=write_quorum,
                                                 ec=ec)
         self.container = self.ccontainer.target(0)
@@ -2971,11 +2813,7 @@ class ROS2Client:
         # ---- client side (host or DPU) ----
         self.client_registry = MemoryRegistry("dpu" if mode == "dpu"
                                               else "host")
-        crypto = None
-        if inline_encryption:
-            # zero_copy=False disables the keystream cache too (PR-1 cost)
-            crypto = InlineCrypto(0xC0FFEE) if zero_copy \
-                else InlineCrypto(0xC0FFEE, cache_bytes=0)
+        crypto = InlineCrypto(0xC0FFEE) if inline_encryption else None
         self._crypto = crypto
         # one data-plane session per target: its own staging ring, rkey
         # grants and transport endpoint against that target's registry
@@ -2989,66 +2827,43 @@ class ROS2Client:
                 self._sessions, self.control, self.client_registry, tenant,
                 make_session=self._attach_target_session,
                 cluster_stats=lambda: self.cluster.stats,
-                zero_copy=zero_copy,
                 faults=fault_injector, timeouts=self.timeouts,
                 redundancy_key="pool0/cont0", crypto=crypto,
                 io_depth=self.io_depth)
         # ---- session bring-up ----
-        rkey, rkey_ttl = None, None
-        if legacy:
-            # the seed's one-RPC-per-step bring-up (the ≥4-round-trip
-            # baseline the compound path is measured against)
-            r = self.control.rpc("connect", tenant=tenant, secret=secret)
-            if not r["ok"]:
-                raise PermissionError(r["error"])
-            self.session_id = r["session_id"]
-            self.control.rpc("mount", session_id=self.session_id,
-                             pool="pool0", container="cont0")
-            if transport == "rdma":
-                g = self.control.rpc("grant_rkey",
-                                     session_id=self.session_id,
-                                     region_id=self.io.staging.region_id,
-                                     perms="rw", ttl_s=rkey_ttl_s)
-                rkey = g["rkey"]
-            self.cache = None
-            self.io.attach_session(self.session_id, rkey, rkey_ttl,
+        # connect + mount + one grant_rkey PER TARGET (+ the pool map for
+        # routed clients) in ONE compound round-trip
+        ops = [{"method": "connect",
+                "args": {"tenant": tenant, "secret": secret}},
+               {"method": "mount",
+                "args": {"pool": "pool0", "container": "cont0"}}]
+        grant_idx: Dict[int, int] = {}
+        if transport == "rdma":
+            for tid in sorted(self._sessions):
+                grant_idx[tid] = len(ops)
+                ops.append({"method": "grant_rkey", "args": {
+                    "region_id": self._sessions[tid].staging.region_id,
+                    "perms": "rw", "ttl_s": rkey_ttl_s}})
+        map_idx = None
+        if n_targets > 1:
+            map_idx = len(ops)
+            ops.append({"method": "get_pool_map", "args": {}})
+        r = self.control.rpc("compound", ops=ops)
+        if r["completed"] < len(ops):
+            raise PermissionError(r["results"][-1]["error"])
+        self.session_id = r["session_id"]
+        self.cache = MetadataCache(self.control, self.session_id,
+                                   skew_margin=lease_skew)
+        rkeys = {tid: r["results"][i]["rkey"]
+                 for tid, i in grant_idx.items()}
+        rkey_ttl = rkey_ttl_s if transport == "rdma" else None
+        if n_targets == 1:
+            self.io.attach_session(self.session_id, rkeys.get(0), rkey_ttl,
                                    self.cache)
         else:
-            # connect + mount + one grant_rkey PER TARGET (+ the pool map
-            # for routed clients) in ONE compound round-trip
-            ops = [{"method": "connect",
-                    "args": {"tenant": tenant, "secret": secret}},
-                   {"method": "mount",
-                    "args": {"pool": "pool0", "container": "cont0"}}]
-            grant_idx: Dict[int, int] = {}
-            if transport == "rdma":
-                for tid in sorted(self._sessions):
-                    grant_idx[tid] = len(ops)
-                    ops.append({"method": "grant_rkey", "args": {
-                        "region_id":
-                            self._sessions[tid].staging.region_id,
-                        "perms": "rw", "ttl_s": rkey_ttl_s}})
-            map_idx = None
-            if n_targets > 1:
-                map_idx = len(ops)
-                ops.append({"method": "get_pool_map", "args": {}})
-            r = self.control.rpc("compound", ops=ops)
-            if r["completed"] < len(ops):
-                raise PermissionError(r["results"][-1]["error"])
-            self.session_id = r["session_id"]
-            self.cache = MetadataCache(self.control, self.session_id,
-                                       skew_margin=lease_skew)
-            rkeys = {tid: r["results"][i]["rkey"]
-                     for tid, i in grant_idx.items()}
-            if transport == "rdma":
-                rkey, rkey_ttl = rkeys.get(0), rkey_ttl_s
-            if n_targets == 1:
-                self.io.attach_session(self.session_id, rkey, rkey_ttl,
-                                       self.cache)
-            else:
-                self.io.attach_session(self.session_id, rkeys, rkey_ttl,
-                                       self.cache,
-                                       pool_map=r["results"][map_idx])
+            self.io.attach_session(self.session_id, rkeys, rkey_ttl,
+                                   self.cache,
+                                   pool_map=r["results"][map_idx])
         self.dfs = DFSClient(self.control, self.io, self.session_id,
                              cache=self.cache)
         # lease renewal runs where the client runs: DPU housekeeping on an
@@ -3073,15 +2888,14 @@ class ROS2Client:
             self.dpu.register("readv", self.dfs.preadv)
             self.dpu.register("writev", self.dfs.pwritev)
             self.dpu.start()
-            if self.cache is not None:
-                self.dpu.start_housekeeping("lease-renew",
-                                            self.cache.renew_due, renew_s)
-        elif self.cache is not None:
+            self.dpu.start_housekeeping("lease-renew",
+                                        self.cache.renew_due, renew_s)
+        else:
             self.cache.start_renewal(renew_s)
-        if zero_copy and scrub_interval_s is not None:
+        if scrub_interval_s is not None:
             # the verified cache is only honest while the scrubber bounds
-            # the silent-corruption window — run it whenever the cache runs.
-            # Started LAST so a failed construction never leaks the thread.
+            # the silent-corruption window, so it runs unless the caller
+            # opts out (`scrub_interval_s=None`). Started LAST so a failed construction never leaks the thread.
             # In dpu mode the pacing runs as DPU housekeeping on an Arm
             # core (the near-NIC background work the offload model keeps
             # off the host), same as lease renewal.
@@ -3103,12 +2917,10 @@ class ROS2Client:
                          t.registry, self.transport, self.tenant,
                          self.control, self._crypto,
                          n_staging_slots=self._n_staging_slots,
-                         legacy=self.legacy, zero_copy=self.zero_copy,
                          target_up=lambda tid=tid:
                              self.cluster.pool_map.is_up(tid),
                          faults=self.faults, timeouts=self.timeouts,
-                         label=f"t{tid}", io_depth=self.io_depth,
-                         tcp_registered=self.tcp_registered)
+                         label=f"t{tid}", io_depth=self.io_depth)
 
     def _attach_target_session(self, tid: int) -> _ServerIO:
         """Router factory for a target discovered on a map refresh
@@ -3344,8 +3156,7 @@ class ROS2Client:
             self.dfs.flush_meta()
         except DFSError:
             pass                     # e.g. every pending path was unlinked
-        if self.cache is not None:
-            self.cache.stop_renewal()
+        self.cache.stop_renewal()
         self.scrubber.stop()
         if self.dpu:
             # never-doorbelled queued submissions die with the client
@@ -3365,35 +3176,3 @@ class ROS2Client:
         # single-target session both expose close() now
         self.io.close()
         self.cluster.close()   # drain background replica commits fleet-wide
-
-    # ---- calibrated performance model ----
-    def stations(self, io_size: int, write: bool,
-                 client_cores: Optional[int] = None,
-                 server_cores: int = tm.SRV_CORES_DEFAULT) -> List[Station]:
-        """One client's service-demand pipeline. Multi-target clients
-        stripe across every engine's cores and devices (server CPU and
-        media capacity scale with the fleet); the network station stays a
-        single link — one client cannot exceed its own NIC, which is
-        exactly why fleet-capacity numbers (bench_data_path's `cluster`
-        section) multiply the per-target pipeline by the placement spread
-        instead of modeling one giant client."""
-        plat = tm.DPU if self.mode == "dpu" else tm.HOST
-        cores = client_cores or plat.n_cores
-        n_targets = len(self.cluster.targets)
-        return (tm.client_stations(plat, self.transport, io_size, write,
-                                   cores)
-                + tm.network_stations(io_size)
-                + tm.server_stations(self.transport, io_size, write,
-                                     server_cores * n_targets)
-                + striped_stations(self.cluster.devices, io_size, write))
-
-    def model_throughput(self, io_size: int, write: bool, jobs: int,
-                         iodepth: int = 8, **kw) -> float:
-        """Modeled B/s for a FIO-like closed workload."""
-        x, _ = mva(self.stations(io_size, write, **kw), jobs * iodepth)
-        return x * io_size
-
-    def model_iops(self, io_size: int, write: bool, jobs: int,
-                   iodepth: int = 8, **kw) -> float:
-        x, _ = mva(self.stations(io_size, write, **kw), jobs * iodepth)
-        return x

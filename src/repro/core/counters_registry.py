@@ -2,8 +2,8 @@
 stack emits.
 
 Motivation: counters are the observability contract of the whole fleet —
-the benchmark gates, the fault-soak assertions and the smoke gate all key
-off `data_path_counters()` dict keys and `note_recovery()` path strings.
+the chip benchmark's metrics and the tier-1 and fault-soak assertions all
+key off `data_path_counters()` dict keys and `note_recovery()` path strings.
 A typo'd key (`"ec.cel_retry"`) is silent: it ships a counter nobody
 reads and starves the assertion that was supposed to watch it.  This
 module closes that hole from both sides:
@@ -38,7 +38,7 @@ TRANSPORT_KEYS = frozenset({
     "bytes_moved", "copies", "copy_bytes", "segments", "control_msgs",
     "ops", "rendezvous", "eager", "sg_ops", "descriptors",
     "rkey_resolves", "rkey_cache_hits", "sendmsg_batches",
-    "placements", "placed_bytes", "registered_read_bytes",
+    "placements", "placed_bytes",
 })
 
 ENGINE_KEYS = frozenset({

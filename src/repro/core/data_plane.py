@@ -189,8 +189,6 @@ class TransportStats:
     sendmsg_batches: int = 0       # TCP iovec batches (1 syscall-equivalent)
     placements: int = 0            # server-initiated direct-splice ops
     placed_bytes: int = 0          # bytes landed by direct placement
-    registered_read_bytes: int = 0  # TCP read bytes landed via the
-    # registered-buffer leg (single copy, no kernel staging bounce)
 
 
 # One scatter-gather descriptor: (remote_offset, local_mr, local_offset, size)
@@ -393,28 +391,15 @@ class TCPTransport:
 
     `read_sg`/`write_sg` exist for API parity with RDMA, but TCP has no
     scatter-gather offload for the DATA: every descriptor is still an
-    MTU-segmented, double-copied stream. With `sendmsg_batching=True`
-    (default) the CONTROL side models `sendmsg`/`recvmsg` iovec batching —
-    the whole sg op's descriptor list ships as ONE request message (one
-    syscall-equivalent), the way a real client coalesces an iovec into a
-    single msghdr. Copies and segments are untouched, so the counters keep
-    discriminating the transports; `sendmsg_batching=False` reproduces the
-    PR-1 per-descriptor request tax.
+    MTU-segmented, double-copied stream. The CONTROL side models
+    `sendmsg`/`recvmsg` iovec batching — the whole sg op's descriptor list
+    ships as ONE request message (one syscall-equivalent), the way a real
+    client coalesces an iovec into a single msghdr. Copies and segments
+    are untouched, so the counters keep discriminating the transports."""
 
-    `registered=True` models the io_uring registered-buffer receive leg:
-    READ payloads whose destinations were registered up front land with
-    ONE copy per byte (kernel -> pinned user pages, no staging bounce
-    through the shared socket buffer), counted in
-    `registered_read_bytes`. MTU segmentation and the request-message
-    economy are unchanged, and the WRITE side keeps the classic two-copy
-    stream — registration helps the receive path only."""
-
-    def __init__(self, local: MemoryRegistry, remote: MemoryRegistry,
-                 sendmsg_batching: bool = True, registered: bool = False):
+    def __init__(self, local: MemoryRegistry, remote: MemoryRegistry):
         self.local = local
         self.remote = remote
-        self.sendmsg_batching = sendmsg_batching
-        self.registered = registered
         self.stats = TransportStats()
         self.faults = None            # optional FaultInjector (core.faults)
         self._kernel_buf = np.zeros(KERNEL_BUF, np.uint8)
@@ -448,36 +433,12 @@ class TCPTransport:
         with self._kbuf_lock:
             self.stats.bytes_moved += size
 
-    def _stream_registered(self, src: np.ndarray, so: int, dst: np.ndarray,
-                           do: int, size: int) -> None:
-        """Registered-buffer receive leg: the destination pages are pinned
-        up front, so each MTU segment is ONE kernel->user copy straight
-        into them — the staging bounce `_stream` pays is gone. The stats
-        lock still serializes segments (per-socket-buffer ordering)."""
-        sent = 0
-        while sent < size:
-            seg = min(MTU, size - sent, KERNEL_BUF)
-            with self._kbuf_lock:
-                dst[do + sent:do + sent + seg] = src[so + sent:so + sent + seg]
-                self.stats.copies += 1
-                self.stats.copy_bytes += seg
-                self.stats.segments += 1
-            sent += seg
-        with self._kbuf_lock:
-            self.stats.bytes_moved += size
-            self.stats.registered_read_bytes += size
-
-    def _recv_stream(self):
-        """The receive-leg stream in force: registered (single-copy) or
-        classic kernel-staged (two-copy)."""
-        return self._stream_registered if self.registered else self._stream
-
     def read(self, region: MemoryRegion, roff: int, local_mr: MemoryRegion,
              loff: int, size: int) -> None:
         with self._kbuf_lock:
             self.stats.ops += 1
             self.stats.control_msgs += 1          # request message
-        self._recv_stream()(region.buf, roff, local_mr.buf, loff, size)
+        self._stream(region.buf, roff, local_mr.buf, loff, size)
 
     def write(self, region: MemoryRegion, roff: int, local_mr: MemoryRegion,
               loff: int, size: int) -> None:
@@ -488,28 +449,24 @@ class TCPTransport:
 
     def _sg_control(self, iov: Sequence[SGDescriptor]) -> None:
         """Request-message accounting for a vectored op: one batched
-        sendmsg for the whole iovec, or one request per descriptor."""
+        sendmsg for the whole iovec."""
         self.stats.ops += 1
         self.stats.sg_ops += 1
         self.stats.descriptors += len(iov)
-        if self.sendmsg_batching:
-            self.stats.control_msgs += 1
-            self.stats.sendmsg_batches += 1
-        else:
-            self.stats.control_msgs += len(iov)
+        self.stats.control_msgs += 1
+        self.stats.sendmsg_batches += 1
 
     # -- vectored API parity (data: per-descriptor double-copied streams) ----
     def read_sg(self, region: MemoryRegion,
                 iov: Sequence[SGDescriptor]) -> int:
-        recv = self._recv_stream()
         with self._kbuf_lock:                     # concurrent SG callers
             self._sg_control(iov)
         if iov:
             r0, l0, o0, s0 = iov[0]
-            self._sg_fault("read_sg", partial=lambda: recv(
+            self._sg_fault("read_sg", partial=lambda: self._stream(
                 region.buf, r0, l0.buf, o0, s0))
         for roff, lmr, loff, size in iov:
-            recv(region.buf, roff, lmr.buf, loff, size)
+            self._stream(region.buf, roff, lmr.buf, loff, size)
         return sum(d[3] for d in iov)
 
     def write_sg(self, region: MemoryRegion,

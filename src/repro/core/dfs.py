@@ -17,7 +17,7 @@ ZERO control RPCs — and holds a size delegation while a file is open:
 `pwrite`/`pwritev` track the size locally and ONE piggybacked `set_size`
 flushes it at `close`/`fsync` (an NFSv4-style write delegation), so the
 canonical open→pwritev→close cycle costs at most two round-trips. Without
-a cache (legacy clients) every op is a round-trip, as before.
+a cache every op is a round-trip.
 """
 from __future__ import annotations
 
@@ -206,7 +206,7 @@ class DFSClient:
         self.cp = control
         self.io = io_service            # server-side I/O engine adapter
         self.session_id = session_id
-        self.cache = cache              # MetadataCache or None (legacy)
+        self.cache = cache              # MetadataCache or None (uncached)
         self._fds = itertools.count(3)
         self._open: Dict[int, FileHandle] = {}
         # size delegation: path -> highest locally-known size not yet
@@ -373,14 +373,12 @@ class DFSClient:
         return self.submit_pread(fd, size, offset, _inline=True).wait()
 
     def preadv(self, fd: int, sizes, offset: int) -> List[bytes]:
-        """Vectored read: one gather op over the contiguous range. On the
-        zero-copy path the SG descriptors scatter straight into the
-        per-size result buffers (`readv_into`) — no contiguous
-        intermediate `bytes` is materialized and re-sliced; the only
-        remaining copy is the `bytes` materialization the return type
-        demands. Falls back to the contiguous blob+slice path when the
-        I/O adapter lacks vectored fill (legacy / PR-1 sg mode).
-        Blocking = submit + wait (op surface defined in `submit_preadv`)."""
+        """Vectored read: one gather op over the contiguous range. The SG
+        descriptors scatter straight into the per-size result buffers
+        (`readv_into`) — no contiguous intermediate `bytes` is
+        materialized and re-sliced; the only remaining copy is the `bytes`
+        materialization the return type demands. Blocking = submit + wait
+        (op surface defined in `submit_preadv`)."""
         return self.submit_preadv(fd, sizes, offset, _inline=True).wait()
 
     # -- async submit/reap -----------------------------------------------
@@ -407,26 +405,14 @@ class DFSClient:
                       timeout: Optional[float] = None,
                       _inline: bool = False):
         """Queue a vectored read; the handle's wait() yields the per-size
-        list of bytes. Result assembly (`tobytes` / blob slicing) is
-        composed into the op via `_then` — it runs on the completing
-        thread, never under the CQ lock."""
+        list of bytes. Result assembly (`tobytes`) is composed into the op
+        via `_then` — it runs on the completing thread, never under the
+        CQ lock."""
         h = self._handle(fd)
-        sizes = [int(s) for s in sizes]
-        if getattr(self.io, "supports_readv_into", False):
-            bufs = [np.empty(s, np.uint8) for s in sizes]
-            return self.io.submit_readv_into(
-                h.oid, offset, bufs, timeout=timeout, _inline=_inline,
-                _then=lambda _n, bs=bufs: [b.tobytes() for b in bs])
-
-        def slice_out(blob: bytes) -> List[bytes]:
-            out, pos = [], 0
-            for s in sizes:
-                out.append(blob[pos:pos + s])
-                pos += s
-            return out
-        return self.io.submit_read(h.oid, offset, sum(sizes),
-                                   timeout=timeout, _inline=_inline,
-                                   _then=slice_out)
+        bufs = [np.empty(int(s), np.uint8) for s in sizes]
+        return self.io.submit_readv_into(
+            h.oid, offset, bufs, timeout=timeout, _inline=_inline,
+            _then=lambda _n, bs=bufs: [b.tobytes() for b in bs])
 
     def pread_into(self, fd: int, size: int, offset: int,
                    dst_mr, dst_off: int = 0) -> int:

@@ -21,9 +21,7 @@ IODEPTH = 8                      # FIO iodepth per job (closed-loop jobs)
 # io_uring submission/completion path per I/O on one core, split into the
 # per-SQE cost (sqe/cqe handling, page pinning) and the per-doorbell
 # syscall/batch cost amortized over the queue depth — the same SQ/CQ
-# model the async client's `io_depth` knob drives, so the bench's 4× gate
-# calibrates against the modeled ceiling at ITS depth instead of a magic
-# constant. At the calibration depth (IODEPTH=8) the per-op sum is
+# model the async client's `io_depth` knob drives. At the calibration depth (IODEPTH=8) the per-op sum is
 # bit-identical to the historical flat 10.0e-6 constant.
 IOURING_PER_SQE = 8.6e-6
 IOURING_DOORBELL = 11.2e-6

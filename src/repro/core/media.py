@@ -12,7 +12,6 @@ service-demand constants calibrated to the paper's Fig. 3 local ceilings:
 from __future__ import annotations
 
 import threading
-import zlib
 from dataclasses import dataclass, field
 from functools import lru_cache
 from time import sleep as time_sleep
@@ -254,12 +253,6 @@ def fletcher64(data) -> int:
         s2 = int((w * _fletcher_weights(w.size)).sum(
             dtype=np.uint64)) & 0xFFFFFFFF
     return (s2 << 32) | s1
-
-
-def crc32_checksum(data) -> int:
-    """The seed's scalar CRC32 extent checksum; kept for the `legacy=True`
-    data path so benchmarks measure against the original per-block path."""
-    return zlib.crc32(bytes(data)) & 0xFFFFFFFF
 
 
 def checksum(data) -> int:

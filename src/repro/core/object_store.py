@@ -365,7 +365,7 @@ class DAOSObject:
                     csum = 0
                 else:                 # inline commit keeps the sync csum
                     csum_fut = None
-                    csum = store.csum(payload)
+                    csum = checksum(payload)
                     with store._stats_lock:
                         store.stats.checksum_bytes += n
                 keys: Dict[str, int] = {}
@@ -780,7 +780,7 @@ class DAOSObject:
             with store._stats_lock:
                 store.stats.verify_hits += 1
                 store.stats.checksum_skipped_bytes += n
-        elif store.csum(data) != ext.csum:
+        elif checksum(data) != ext.csum:
             with store._stats_lock:
                 store.stats.verify_misses += 1
                 store.stats.checksum_bytes += n
@@ -849,7 +849,7 @@ class DAOSObject:
                 dev.write(key, payload)
                 keys[name] = key
             replacements.append(Extent(ext.offset, keep, ext.epoch,
-                                       cont.store.csum(payload), keys))
+                                       checksum(payload), keys))
         gone = set(map(id, dead)) | set(map(id, straddle))
         with self._lock:
             lst = self._extents.get((dkey, akey), [])
@@ -1049,19 +1049,15 @@ class Pool:
 class ObjectStore:
     """The DAOS I/O engine's storage core (one per storage server).
 
-    `csum` selects the end-to-end extent checksum: the default is the
-    vectorized Fletcher-64 (media.checksum, matching the fletcher Pallas
-    kernel); pass media.crc32_checksum to reproduce the seed's scalar CRC
-    path (the `legacy=True` benchmark baseline)."""
+    Every extent carries the vectorized Fletcher-64 end-to-end checksum
+    (media.checksum, matching the fletcher Pallas kernel)."""
 
     def __init__(self, devices: List[Device],
-                 csum: Optional[Callable[[bytes], int]] = None,
                  timeouts: Timeouts = DEFAULT_TIMEOUTS):
         assert devices, "need at least one device"
         self.devices = devices
         self.pools: Dict[str, Pool] = {}
         self._block_keys = itertools.count(1)
-        self.csum = csum or checksum
         self.timeouts = timeouts
         # optional fault injector (faults.py); wired by the owner, shared
         # with the devices/cluster so one schedule spans every layer
@@ -1084,7 +1080,7 @@ class ObjectStore:
         """Write-path Fletcher-64, run on a commit worker so the quorum
         fan-out overlaps the per-byte checksum with the replica media
         writes instead of paying it synchronously on the op thread."""
-        c = self.csum(payload)
+        c = checksum(payload)
         with self._stats_lock:
             self.stats.checksum_bytes += _nbytes(payload)
             self.stats.checksum_offloads += 1
@@ -1483,15 +1479,13 @@ class StorageCluster:
         placement primary (the rebuild path's read-verify-write-punch).
 
     The facade also mirrors the ObjectStore surfaces fleet-level services
-    consume (`containers()`, `devices`, `device()`, `csum`, `stats`), so a
+    consume (`containers()`, `devices`, `device()`, `stats`), so a
     MediaScrubber pointed at the cluster scrubs every target's verified
     cache."""
 
     def __init__(self, n_targets: int = 1, n_devices: int = 4,
-                 csum: Optional[Callable[[bytes], int]] = None,
                  timeouts: Timeouts = DEFAULT_TIMEOUTS,
                  domains: Optional[Sequence[Optional[str]]] = None):
-        self.csum = csum or checksum
         self.n_devices = int(n_devices)
         self.timeouts = timeouts
         self.faults: Optional[FaultInjector] = None
@@ -1524,7 +1518,7 @@ class StorageCluster:
         tid = len(self.targets)
         store = ObjectStore(
             make_nvme_array(n_devices or self.n_devices, prefix=f"t{tid}."),
-            csum=self.csum, timeouts=self.timeouts)
+            timeouts=self.timeouts)
         store.on_spareless_demotion = self._heal_cross_target
         if self.faults is not None:
             store.faults = self.faults
@@ -1994,7 +1988,7 @@ class MediaScrubber:
                     cont.vcache.invalidate_block(name, key)
                     continue
                 scanned += n
-                if self.store.csum(data) != csum:
+                if checksum(data) != csum:
                     cont.vcache.invalidate_block(name, key)
                     revoked += 1
         with self.store._stats_lock:

@@ -88,7 +88,8 @@ class InlineCrypto:
     memoized in an LRU so steady-state re-reads of the same blocks pay zero
     PRF regeneration; `apply_into` fuses the XOR with the splice into the
     caller's buffer (one pass, no temporary). `cache_bytes=0` disables the
-    cache (the PR-1 regenerate-every-op behavior, kept for benchmarks)."""
+    cache: every op regenerates its keystream, the uncached reference the
+    keystream tests compare against."""
 
     def __init__(self, key: int, cache_bytes: int = KEYSTREAM_CACHE_BYTES):
         # fold 64-bit keys into the u32 lane the kernel PRF uses (high half

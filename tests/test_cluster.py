@@ -67,6 +67,21 @@ def test_placement_spreads_blocks():
     assert primaries == {0, 1}
 
 
+@pytest.mark.parametrize("n,doms", [
+    (2, None), (8, ("a", "a", "b", "b", "c", "c", "d", "d"))],
+    ids=["flat_2", "domains_8"])
+def test_placement_population_spread_near_linear(n, doms):
+    """Striped reads scale with the fleet only as far as primaries
+    spread: over a 64-object x 64-block population no target is primary
+    for more than 1 / (0.8 n) of the blocks (>= 0.8x linear), with or
+    without fault-domain labels."""
+    counts = [0] * n
+    for oid in range(1, 65):
+        for b in range(64):
+            counts[placement_order(n, oid, str(b), doms)[0]] += 1
+    assert max(counts) / (64 * 64) <= 1 / (0.8 * n), counts
+
+
 def test_router_memoizes_placement_and_invalidates_on_map_change():
     """The router computes placement_order once per (oid, dkey) and
     serves repeats from an LRU keyed off the adopted map version: a
@@ -418,6 +433,35 @@ def test_dpu_mode_two_targets_roundtrip():
     data = _payload(3 * BLOCK, seed=17)
     c.pwrite(fd, data, 0)
     assert c.pread(fd, len(data), 0) == data
+    c.close()
+
+
+def test_routed_warm_reads_translate_once_and_skip_checksums():
+    """On an 8-target four-domain fleet every target serves placements,
+    each bulk op pays ONE rendezvous, each session translates each of its
+    two regions (staging ring, read sink) once ever, and a warm re-read
+    runs no checksum: the verified-extent cache is on for the client."""
+    c = ROS2Client(mode="host", transport="rdma", n_targets=8,
+                   domains=["a", "a", "b", "b", "c", "c", "d", "d"],
+                   scrub_interval_s=None)
+    fd = c.open("/warm", create=True)
+    data = _payload(32 * BLOCK, seed=19)
+    for off in range(0, len(data), 8 * BLOCK):
+        c.pwrite(fd, data[off:off + 8 * BLOCK], off)
+    sink = c.register_region(len(data))
+    c.pread_into(fd, len(data), 0, sink, 0)      # cold: verifies + caches
+    warm = c.io.data_path_counters()
+    c.pread_into(fd, len(data), 0, sink, 0)
+    after = c.io.data_path_counters()
+    assert bytes(sink.buf) == data
+    assert after["engine"]["checksum_bytes"] \
+        == warm["engine"]["checksum_bytes"]
+    assert after["engine"]["checksum_skipped_bytes"] \
+        - warm["engine"]["checksum_skipped_bytes"] >= len(data)
+    tr = after["transport"]
+    assert tr["rendezvous"] == tr["sg_ops"]
+    assert tr["rkey_resolves"] <= 2 * 8
+    assert all(s.stats.placed_bytes > 0 for s in c.io.sessions.values())
     c.close()
 
 

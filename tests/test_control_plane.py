@@ -12,7 +12,7 @@ import pytest
 
 from repro.core.client import ROS2Client
 from repro.core.control_plane import ControlPlane
-from repro.core.data_plane import AccessError, MemoryRegistry
+from repro.core.data_plane import MemoryRegistry
 from repro.core.dfs import BLOCK, DFSError, DFSMeta
 from repro.core.media import make_nvme_array
 from repro.core.metadata_cache import MetadataCache
@@ -141,19 +141,6 @@ def test_rkey_renewal_extends_lease_in_place():
     now[0] = 0.08                        # back inside the margin
     assert cache.renew_due() == 0        # server refuses the renewal
     assert not cache.rkey_fresh(token)   # dropped from the lease watch
-
-
-def test_expired_rkey_hard_faults_without_renewal():
-    """The pre-PR-3 failure mode, pinned: an rkey that lapses mid-run is a
-    hard data-plane fault (legacy client, no lease watch)."""
-    c = ROS2Client(mode="host", transport="rdma", legacy=True,
-                   rkey_ttl_s=0.05, scrub_interval_s=None)
-    fd = c.open("/f", create=True)
-    c.pwrite(fd, b"x" * 1024, 0)
-    time.sleep(0.1)
-    with pytest.raises(AccessError):
-        c.pwrite(fd, b"y" * 1024, 0)
-    c.close()
 
 
 def test_background_renewal_keeps_data_plane_alive():

@@ -97,21 +97,19 @@ def test_steady_state_reads_zero_staging_acquires():
     c.close()
 
 
-def test_tcp_and_sg_paths_still_stage():
-    """The ring stays for TCP (no server-initiated placement without RDMA)
-    and for the PR-1 sg path — and the bounce is now COUNTED."""
-    for kw in (dict(transport="tcp"), dict(transport="rdma",
-                                           zero_copy=False)):
-        c = ROS2Client(mode="host", **kw)
-        fd = c.open("/staged", create=True)
-        data = _payload(2 * BLOCK, seed=2)
-        c.pwrite(fd, data, 0)
-        a0 = c.io.ring.acquires
-        assert c.pread(fd, len(data), 0) == data
-        assert c.io.ring.acquires > a0
-        assert c.io.data_path_counters()["staging"]["bounce_bytes"] \
-            == len(data)
-        c.close()
+def test_tcp_path_still_stages():
+    """The ring stays for TCP (no server-initiated placement without
+    RDMA) — and the bounce is COUNTED."""
+    c = ROS2Client(mode="host", transport="tcp")
+    fd = c.open("/staged", create=True)
+    data = _payload(2 * BLOCK, seed=2)
+    c.pwrite(fd, data, 0)
+    a0 = c.io.ring.acquires
+    assert c.pread(fd, len(data), 0) == data
+    assert c.io.ring.acquires > a0
+    assert c.io.data_path_counters()["staging"]["bounce_bytes"] \
+        == len(data)
+    c.close()
 
 
 def test_revoked_dst_rkey_cannot_receive_direct_splice():

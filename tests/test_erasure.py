@@ -254,8 +254,10 @@ def test_ec_outage_writes_mark_dirty_and_rebuild_regenerates_only_lost():
     for cont in c.ccontainer._per_target.values():
         for _oid, obj in list(cont._objects.items()):
             assert not obj.dkeys(EC_DIRTY_AKEY)
+    degraded = c.io.data_path_counters()["ec"]["degraded_reads"]
     assert c.pread(fd, len(shadow), 0) == shadow  # healthy read, no decode
     ctr = c.io.data_path_counters()
+    assert ctr["ec"]["degraded_reads"] == degraded
     assert ctr["ec"]["rebuilt_cells"] == c.cluster.stats.ec_rebuilt_cells
     c.close()
 
@@ -712,6 +714,33 @@ def test_ec_wide_geometry_roundtrip_degraded_rebuild(ec, n, doms):
     assert not _dirty_union(c, k + p)
     assert c.pread(fd, len(shadow), 0) == bytes(shadow)
     _assert_rings_whole(c)
+    c.close()
+
+
+@pytest.mark.parametrize("ec,n,doms", _WIDE,
+                         ids=["ec42_at_8", "ec83_at_12"])
+def test_ec_delta_overwrite_wire_budget(ec, n, doms):
+    """A one-cell overwrite of a clean stripe moves (1 new + 1 old + p
+    parity) cells over the wire — never the k-cell stripe read of the
+    full re-encode. This is the budget the chip metric
+    `wire_bytes_per_user_byte.small` rests on."""
+    c = _client(n_targets=n, ec=ec, domains=doms)
+    k, p, cs = c.io._ec
+    fd = c.open("/f", create=True)
+    shadow = bytearray(_payload(2 * BLOCK, 74))
+    c.pwrite(fd, bytes(shadow), 0)
+    before = c.io.data_path_counters()            # drains stragglers
+    cell = _payload(cs, 75)
+    c.pwrite(fd, cell, 0)
+    after = c.io.data_path_counters()
+    shadow[:cs] = cell
+    wire = after["transport"]["bytes_moved"] \
+        - before["transport"]["bytes_moved"]
+    assert wire <= (2 + p) * cs + cs // 8, (wire, (2 + p) * cs)
+    assert after["ec"]["delta_writes"] - before["ec"]["delta_writes"] == 1
+    assert after["ec"]["delta_bytes_saved"] \
+        - before["ec"]["delta_bytes_saved"] >= (k - 1) * cs
+    assert c.pread(fd, len(shadow), 0) == bytes(shadow)
     c.close()
 
 

@@ -11,7 +11,7 @@ from repro.core.client import ROS2Client
 from repro.core.data_plane import (AccessError, MemoryRegistry, MTU,
                                    RDMATransport, TCPTransport)
 from repro.core.dfs import BLOCK
-from repro.core.media import checksum, crc32_checksum, make_nvme_array
+from repro.core.media import checksum, make_nvme_array
 from repro.core.object_store import ObjectStore
 
 
@@ -70,22 +70,6 @@ def test_sg_counters_tcp_two_copies_per_byte():
     # descriptor list ships as a single msghdr), data still double-copied
     assert s.control_msgs == s.sg_ops
     assert s.sendmsg_batches == s.sg_ops
-    c.close()
-
-
-def test_tcp_without_sendmsg_batching_pays_per_descriptor():
-    """zero_copy=False reproduces the PR-1 control tax: one request
-    message per descriptor (no iovec coalescing)."""
-    c = ROS2Client(mode="host", transport="tcp", zero_copy=False)
-    fd = c.open("/sg", create=True)
-    data = _payload(2 * BLOCK, seed=1)
-    c.pwrite(fd, data, 0)
-    assert c.pread(fd, len(data), 0) == data
-    s = c.io.stats
-    assert s.sendmsg_batches == 0
-    assert s.control_msgs == s.descriptors == 4
-    # data-side semantics identical either way
-    assert s.copy_bytes == 2 * s.bytes_moved
     c.close()
 
 
@@ -312,21 +296,6 @@ def test_pwritev_preadv_roundtrip_one_set_size_rpc(mode):
     c.close_fd(fd)
     assert c.control.rpc_count == before + 1    # the piggybacked flush
     assert c.dfs.stat("/v")["size"] == n        # durable on the server
-    c.close()
-
-
-def test_legacy_flag_reproduces_per_block_path():
-    c = ROS2Client(mode="host", transport="rdma", legacy=True)
-    assert c.store.csum is crc32_checksum
-    fd = c.open("/l", create=True)
-    data = _payload(4 * BLOCK, seed=8)
-    c.pwrite(fd, data, 0)
-    assert c.pread(fd, len(data), 0) == data
-    s = c.io.stats
-    assert s.sg_ops == 0                         # per-block scalar verbs
-    assert s.ops == 2 * 4                        # one op per block each way
-    assert s.rendezvous == s.ops                 # per-block RTS/CTS
-    assert s.rkey_resolves == s.ops              # no translation cache
     c.close()
 
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.client import ROS2Client, SlotLease, _StagingRing
-from repro.core.dfs import BLOCK
+from repro.core.dfs import AKEY, BLOCK
 from repro.core.media import make_nvme_array
 from repro.core.object_store import MediaScrubber, ObjectStore
 from repro.core.smartnic import KEYSTREAM_PAGE, InlineCrypto
@@ -405,18 +405,6 @@ def test_zero_copy_write_path_has_zero_post_splice_copies():
     c.close()
 
 
-def test_sg_path_pays_materialization_copy():
-    c = ROS2Client(mode="host", transport="rdma", zero_copy=False)
-    fd = c.open("/sg", create=True)
-    data = _payload(4 * BLOCK, seed=8)
-    c.pwrite(fd, data, 0)
-    ctr = c.io.data_path_counters()
-    assert ctr["client"]["host_copy_bytes"] == 4 * BLOCK    # tobytes/block
-    assert ctr["media"]["donated_bytes"] == 0
-    assert c.pread(fd, len(data), 0) == data
-    c.close()
-
-
 def test_encrypted_zero_copy_roundtrip_and_keystream_cache():
     c = ROS2Client(mode="host", transport="rdma", inline_encryption=True)
     fd = c.open("/enc", create=True)
@@ -436,16 +424,63 @@ def test_encrypted_zero_copy_roundtrip_and_keystream_cache():
     c.close()
 
 
-def test_legacy_and_zero_copy_interoperate_on_stored_bytes():
-    """The seed per-block path and the zero-copy path share InlineCrypto
-    nonce/offset conventions: bytes written by one decrypt under the
-    other (same engine, both entry points of the same adapter)."""
-    c = ROS2Client(mode="host", transport="rdma", inline_encryption=True)
-    data = _payload(BLOCK + 123, seed=11)
-    c.io._write_legacy(1000, 0, data)            # seed per-block writer
-    assert c.io.read(1000, 0, len(data)) == data  # zero-copy reader
-    data2 = _payload(BLOCK + 123, seed=12)
-    c.io.write(2000, 0, data2)                   # zero-copy writer
-    out = c.io._read_legacy(2000, 0, len(data2))  # seed per-block reader
-    assert out == data2
+_ENC_TOPOLOGIES = {
+    "single": dict(),
+    "rp2": dict(n_targets=4, replication=2),
+    "ec42": dict(n_targets=8, ec=(4, 2),
+                 domains=["a", "a", "b", "b", "c", "c", "d", "d"]),
+}
+
+
+def _stored_bytes(c, oid, b, bo, n):
+    """Media bytes of block `b` at [bo, bo+n), fetched straight from the
+    engine that holds them: no transport, no decrypt."""
+    io = c.io
+    if not hasattr(io, "sessions"):
+        cont = io.container
+    elif io._ec is not None:
+        _k, _p, cs = io._ec
+        cont = io.sessions[io._ec_order(oid, b)[bo // cs]].container
+    else:
+        cont = io.sessions[io._route_block(oid, b)].container
+    return cont.object(oid).fetch(str(b), AKEY, bo, n)
+
+
+@pytest.mark.parametrize("topology", list(_ENC_TOPOLOGIES))
+def test_encrypted_unaligned_write_decrypts_at_block_offsets(topology):
+    """Inline encryption keys every byte by (oid, block) nonce and its
+    block-ABSOLUTE offset: a writev that starts mid-block stores
+    ciphertext at the offsets the stream cipher oracle gives, and reads
+    whose ranges start mid-block — through `read`, `read_into` and
+    `readv_into` — decrypt at those same offsets, on a single target, a
+    routed RP_2 fleet and a routed ec(4,2) fleet."""
+    c = ROS2Client(mode="host", transport="rdma", inline_encryption=True,
+                   scrub_interval_s=None, **_ENC_TOPOLOGIES[topology])
+    fd = c.open("/enc-unaligned", create=True)
+    oid = c.dfs._handle(fd).oid
+    base = 12345                                 # not block-aligned
+    data = _payload(2 * BLOCK + 777, seed=13)
+    c.pwritev(fd, [data[:BLOCK - 100], data[BLOCK - 100:BLOCK + 5000],
+                   data[BLOCK + 5000:]], base)
+    if hasattr(c.io, "sessions"):
+        c.io.data_path_counters()                # land EC stragglers
+    # ciphertext at rest: block b's bytes at bo are keyed (oid<<20 | b, bo)
+    end = base + len(data)
+    for b in range(base // BLOCK, (end - 1) // BLOCK + 1):
+        bo = max(base, b * BLOCK) - b * BLOCK
+        n = min(64, end - b * BLOCK - bo)
+        plain = np.frombuffer(data, np.uint8)[b * BLOCK + bo - base:][:n]
+        want = plain ^ _oracle_keystream(0xC0FFEE, oid * (1 << 20) + b,
+                                         bo, n)
+        assert _stored_bytes(c, oid, b, bo, n) == want.tobytes(), b
+    for off, n in [(base + 1, 4096), (BLOCK - 50, 300),
+                   (BLOCK + 7, BLOCK), (2 * BLOCK + 3, 700)]:
+        want = data[off - base:off - base + n]
+        assert c.io.read(oid, off, n) == want, off
+        dst = c.register_region(n)
+        assert c.io.read_into(oid, off, n, dst, 0) == n
+        assert bytes(dst.buf) == want, off
+        bufs = [np.empty(7, np.uint8), np.empty(n - 7, np.uint8)]
+        assert c.io.readv_into(oid, off, bufs) == n
+        assert b"".join(x.tobytes() for x in bufs) == want, off
     c.close()
