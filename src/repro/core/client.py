@@ -1550,6 +1550,10 @@ class _ClusterRouter(_SubmitReap):
         # before their parity wait
         self.ec_parity_serial_writes = 0   # writes whose cells all waited
         # for the parity (a data cell's target down)
+        self.ec_op_thread_cells = 0   # cells the op thread committed
+        # itself (data cells and heal-on-write rewrites)
+        self.ec_pool_cells = 0        # cells handed to the router pool
+        # (parity cells)
         self._sid: Optional[int] = None
         self.cache = None
         self._map_lock = threading.Lock()
@@ -2128,15 +2132,20 @@ class _ClusterRouter(_SubmitReap):
         starts the parity call (`nbytes` of input) and returns its
         pending result. Returns the cells that failed (each ledgered).
 
-        The free cells go to the pool first, then the op thread
-        dispatches the parity and hands out the parity jobs: the first
-        of them to run waits for the rows, and the others share them.
-        So the data cells move while the device computes, and a parity
-        cell starts the moment its rows are back. A job waits only on
+        The op thread dispatches the parity, hands the parity jobs to the
+        pool, then commits the free cells itself, one after another. The
+        first parity job to run waits for the rows, and the others share
+        them. So the data cells move while the device computes, and a
+        parity cell starts the moment its rows are back. A cell's commit
+        is Python under the interpreter lock, so data cells spread over
+        pool threads would only contend with each other and with the
+        dispatch; the pool holds only parity jobs. A job waits only on
         the device, never on another job, so a full pool cannot starve
-        the parity. Waits for min(cells, quorum) completions, the rest
-        left in `_ec_pending`, or for every cell when `quorum` is None;
-        marks in `clears` are cleared for the cells that landed.
+        the parity. Waits for min(cells, quorum) completions, the free
+        cells counted as done and the rest left in `_ec_pending`, or for
+        every cell when `quorum` is None; marks in `clears` are cleared
+        for the cells that landed. `ec_op_thread_cells` and
+        `ec_pool_cells` count the cells each side was given.
 
         Data may now land before its parity exists, so a parity call
         that raises costs all p parity cells: the cells handed out are
@@ -2144,7 +2153,9 @@ class _ClusterRouter(_SubmitReap):
         for the data that landed, which agrees with the new image), and
         the write fails. That leaves at most p dirty cells only while no
         data cell is dirty or unreachable, so when a data cell's target
-        is down the parity comes back before any cell moves."""
+        is down the parity comes back before any cell moves; then the
+        parity jobs go to the pool and the free cells run on the op
+        thread as above."""
         k, p, _cs = self._ec
         failed: List[int] = []
         flock = threading.Lock()
@@ -2203,21 +2214,27 @@ class _ClusterRouter(_SubmitReap):
             if early:
                 fan.enter_context(tracing.span("ros2.ec.fanout", op=op,
                                                cells=len(cells)))
-                futs = [pool.submit(run, c, fn) for c, fn in free]
             try:
                 with tracing.span("ros2.ec.parity.submit", op=op,
                                   bytes=nbytes):
                     pending = dispatch()
             except Exception as e:  # lint: allow(broad-except): kept
                 parity.append(e)    # and raised below, as the wait's
-            if not early and not isinstance(rows(), Exception):
-                fan.enter_context(tracing.span("ros2.ec.fanout", op=op,
-                                               cells=len(cells)))
-                futs = [pool.submit(run, c, fn) for c, fn in free]
-            futs += [pool.submit(run_parity, c, fn) for c, fn in needs]
-            # at most k free jobs, so a quorum of k+1 holds a parity job:
-            # the parity's outcome is known once the quorum is in
-            n = len(futs) if quorum is None else min(len(futs), quorum)
+            if early or not isinstance(rows(), Exception):
+                if not early:
+                    fan.enter_context(tracing.span("ros2.ec.fanout", op=op,
+                                                   cells=len(cells)))
+                with self._map_lock:
+                    self.ec_op_thread_cells += len(free)
+                    self.ec_pool_cells += len(needs)
+                futs = [pool.submit(run_parity, c, fn) for c, fn in needs]
+                for c, fn in free:
+                    run(c, fn)
+            # the free cells are in, and at most k of them, so a quorum
+            # of k+1 holds a parity job: the parity's outcome is known
+            # once the quorum is in
+            n = len(futs) if quorum is None \
+                else min(len(cells), quorum) - len(free)
             for i, f in enumerate(as_completed(futs)):
                 f.result()
                 if i + 1 >= n:
@@ -2240,13 +2257,14 @@ class _ClusterRouter(_SubmitReap):
 
     def _ec_write_block(self, rs, oid: int, b: int, bo: int,
                         frag: np.ndarray, op: int = 0) -> str:
-        """One stripe's write: a parallel fan-out of the touched data
-        cells (full session writev path — staging, transport, inline
-        crypto) while the parity over the zero-padded full block in the
-        media domain computes (partial writes read-modify-write the
-        stripe image first), then the p parity cells (raw cell plane).
-        Foreground returns once min(jobs, k+1) cells land; stragglers
-        finish in background.
+        """One stripe's write: the op thread commits the touched data
+        cells itself (full session writev path — staging, transport,
+        inline crypto) while the parity over the zero-padded full block
+        in the media domain computes on the device (partial writes
+        read-modify-write the stripe image first), and the router pool
+        lands the p parity cells (raw cell plane) as the rows come back
+        (`_ec_fanout`). Foreground returns once min(jobs, k+1) cells
+        land; stragglers finish in background.
         Cells on down targets are dropped and marked dirty — more than p
         of them and the stripe would go below k clean cells, which is a
         hard error BEFORE any byte moves.
@@ -2359,9 +2377,10 @@ class _ClusterRouter(_SubmitReap):
         xor'd extent with a stale base); holes read zeros so a first
         write to a sparse stripe deltas against P=0 and lands the exact
         encode; the engine aborts failed commits atomically, so the
-        bounded `_ec_retry` re-reads an unchanged base. The touched cells'
-        writes run beside the parity call (`_ec_fanout`) and every job is
-        waited for — a failed cell is dirty-marked exactly like the
+        bounded `_ec_retry` re-reads an unchanged base. The op thread
+        writes the touched cells while the parity call computes, the
+        pool lands the p `xor_apply` deltas (`_ec_fanout`), and every job
+        is waited for — a failed cell is dirty-marked exactly like the
         full path (parity was applied for the INTENDED new data, so
         rebuild decodes the marked cell to that content)."""
         k, p, cs = self._ec
@@ -2669,6 +2688,8 @@ class _ClusterRouter(_SubmitReap):
                     "parity_coeff_misses": rs.coeff_cache.misses,
                     "parity_overlap_writes": self.ec_parity_overlap_writes,
                     "parity_serial_writes": self.ec_parity_serial_writes,
+                    "op_thread_cells": self.ec_op_thread_cells,
+                    "pool_cells": self.ec_pool_cells,
                 }
         return counters_registry.verify(out)
 

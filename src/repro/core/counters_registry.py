@@ -104,6 +104,7 @@ EC_KEYS = frozenset({
     "delta_writes", "delta_bytes_saved", "delta_fallbacks",
     "parity_coeff_hits", "parity_coeff_misses",
     "parity_overlap_writes", "parity_serial_writes",
+    "op_thread_cells", "pool_cells",
 })
 
 FAULTS_KEYS = frozenset({

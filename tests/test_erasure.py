@@ -567,6 +567,145 @@ def test_ec_parity_failure_ledgers_parity_and_heals(monkeypatch, path):
     c.close()
 
 
+def _record_cell_threads(monkeypatch, c):
+    """Wrap every session's cell verbs to log (verb, thread ident) as
+    the router calls them: `writev` lands a data cell, `update_cell` a
+    parity cell (or a heal rewrite), `xor_apply` a parity delta."""
+    log = []
+    for s in c.io.sessions.values():
+        for verb in ("writev", "update_cell", "xor_apply"):
+            def call(*a, verb=verb, real=getattr(s, verb), **kw):
+                log.append((verb, threading.get_ident()))
+                return real(*a, **kw)
+            monkeypatch.setattr(s, verb, call)
+    return log
+
+
+def _router_pool_idents():
+    return {t.ident for t in threading.enumerate()
+            if t.name.startswith("cluster-router")}
+
+
+def test_ec_op_thread_commits_data_cells_and_pool_lands_parity(monkeypatch):
+    """A full-stripe write and a 4 KiB delta write on ec(4,2): every
+    data cell's `writev` runs on the writing thread, every parity cell
+    on a router-pool thread, `op_thread_cells` / `pool_cells` rise by
+    k / p and by 1 / p, and the stored stripe is the reference encode."""
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    k, p, _cs = c.io._ec
+    fd = c.open("/f", create=True)
+    c.pwrite(fd, _payload(BLOCK, 110), 0)
+    log = _record_cell_threads(monkeypatch, c)
+    shadow = bytearray(_payload(BLOCK, 111))
+    me = threading.get_ident()
+    for off, ln, parity_verb, n_data in ((0, BLOCK, "update_cell", k),
+                                         (8192, 4096, "xor_apply", 1)):
+        before = c.io.data_path_counters()["ec"]
+        log.clear()
+        data = bytes(shadow) if ln == BLOCK else _payload(ln, 112)
+        c.pwrite(fd, data, off)
+        shadow[off:off + ln] = data
+        c.io._ec_drain()
+        after = c.io.data_path_counters()["ec"]
+        assert after["op_thread_cells"] - before["op_thread_cells"] == n_data
+        assert after["pool_cells"] - before["pool_cells"] == p
+        pool = _router_pool_idents()
+        assert [t for v, t in log if v == "writev"] == [me] * n_data
+        par = [t for v, t in log if v == parity_verb]
+        assert len(par) == p and set(par) <= pool and me not in par
+        assert {v for v, _t in log} == {"writev", parity_verb}
+    monkeypatch.undo()
+    _assert_stripe_is_encode(c, 0, bytes(shadow))
+    assert c.pread(fd, BLOCK, 0) == bytes(shadow)
+    c.close()
+
+
+def test_ec_serial_order_lands_free_cells_on_op_thread_after_parity(
+        monkeypatch):
+    """With one data cell's target down the write takes the serial
+    order: the op thread waits for the parity rows, then commits the
+    k - 1 reachable data cells itself while the pool lands the p parity
+    cells. Exactly the down cell is left dirty, and the stripe reads
+    back bit-exact through the outage and after recovery."""
+    from repro.kernels.rs_parity import ops as rs
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    k, p, _cs = c.io._ec
+    fd = c.open("/f", create=True)
+    c.pwrite(fd, _payload(BLOCK, 120), 0)
+    order = c.io._ec_order(_oid(c), 0)
+    c.cluster.fail_target(order[1])
+    log = _record_cell_threads(monkeypatch, c)
+
+    class Logged:
+        def __init__(self, out):
+            self.out = out
+
+        def __array__(self, dtype=None, copy=None):
+            out = np.asarray(self.out, dtype)
+            log.append(("parity", threading.get_ident()))
+            return out
+
+    monkeypatch.setattr(rs, "ec_encode", lambda *a, real=rs.ec_encode,
+                        **kw: Logged(real(*a, **kw)))
+    before = c.io.data_path_counters()["ec"]
+    block = _payload(BLOCK, 121)
+    c.pwrite(fd, block, 0)
+    c.io._ec_drain()
+    monkeypatch.undo()
+    after = c.io.data_path_counters()["ec"]
+    me = threading.get_ident()
+    verbs = [v for v, _t in log]
+    assert log[0] == ("parity", me)
+    assert [t for v, t in log if v == "writev"] == [me] * (k - 1)
+    par = [t for v, t in log if v == "update_cell"]
+    assert len(par) == p and set(par) <= _router_pool_idents()
+    assert verbs.count("parity") == 1
+    assert after["parity_serial_writes"] - before["parity_serial_writes"] \
+        == 1
+    assert after["op_thread_cells"] - before["op_thread_cells"] == k - 1
+    assert after["pool_cells"] - before["pool_cells"] == p
+    assert list(_dirty_union(c, k + p).values()) == [{1}]
+    assert c.pread(fd, BLOCK, 0) == block
+    c.cluster.recover_target(order[1])
+    assert _dirty_union(c, k + p) == {}
+    _assert_stripe_is_encode(c, 0, block)
+    c.close()
+
+
+@pytest.mark.parametrize("path", ["delta", "full"])
+def test_ec_parity_dispatch_failure_ledgers_parity(monkeypatch, path):
+    """A parity call that raises at dispatch, before any cell has moved,
+    still ledgers the p parity cells dirty and fails the write with
+    StorageError from the original error; the next full write heals
+    the stripe to the reference encode."""
+    from repro.kernels.rs_parity import ops as rs
+    c = _client(n_targets=8, ec=(4, 2),
+                domains=["a", "a", "b", "b", "c", "c", "d", "d"])
+    k, p, _cs = c.io._ec
+    fd = c.open("/f", create=True)
+    c.pwrite(fd, _payload(BLOCK, 130), 0)
+    off, ln = (8192, 4096) if path == "delta" else (0, BLOCK)
+
+    def lost(*_a, **_kw):
+        raise RuntimeError("device lost")
+
+    for name in ("ec_encode", "ec_parity_delta"):
+        monkeypatch.setattr(rs, name, lost)
+    with pytest.raises(StorageError) as err:
+        c.pwrite(fd, _payload(ln, 131), off)
+    assert isinstance(err.value.__cause__, RuntimeError)
+    monkeypatch.undo()
+    c.io._ec_drain()
+    assert list(_dirty_union(c, k + p).values()) == [set(range(k, k + p))]
+    block = _payload(BLOCK, 132)
+    c.pwrite(fd, block, 0)
+    assert _dirty_union(c, k + p) == {}
+    _assert_stripe_is_encode(c, 0, block)
+    c.close()
+
+
 def test_ec_delta_falls_back_when_parity_target_down():
     """The delta path needs every touched-data and parity target UP (it
     xors in place; there is no quorum to hide behind). With a parity
